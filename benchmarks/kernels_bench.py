@@ -211,20 +211,20 @@ def main(argv=None):
     nblk = -(-npar // block)
     pad = nblk * block - npar
     xflat = jnp.pad(xx, (0, pad))
-    bsalts = jnp.full((nblk,), 55, jnp.uint32)
-    ctrs = (jnp.arange(nblk, dtype=jnp.uint32) * block)
-    nvalid = jnp.minimum(block, npar - jnp.arange(nblk) * block).astype(jnp.int32)
-    t = timeit(lambda a: ops.zo_perturb_sumsq(a, bsalts, ctrs, nvalid, 1e-3,
-                                              block=block), xflat)
+    # one leaf: the per-leaf layout tables (first block, element count)
+    starts = jnp.zeros((1,), jnp.int32)
+    sizes = jnp.full((1,), npar, jnp.int32)
+    t = timeit(lambda a: ops.zo_perturb_sumsq(
+        a, starts, sizes, jnp.full((1,), 55, jnp.uint32), 1e-3, block=block),
+        xflat)
     row("kern/zo_perturb_sumsq", t, npar * 4 * 2, npar * 4 * 3)
 
-    msalts = jnp.tile(salts[None, :], (nblk, 1))
-    bf16 = jnp.zeros((nblk,), jnp.int32)
+    bf16 = jnp.zeros((1,), jnp.int32)
     # the params buffer is DONATED (updated in place) — hand the kernel a
     # fresh copy per call so timing iterations don't reuse a deleted buffer
     t = timeit(
         lambda a, c_: ops.zo_reconstruct_update(
-            a.copy(), None, msalts, ctrs, nvalid, bf16, c_, 0.05,
+            a.copy(), None, starts, sizes, bf16, salts[:, None], c_, 0.05,
             block=block)[0],
         xflat, coeffs)
     row("kern/zo_reconstruct_update", t, npar * 4 * 2, npar * 4 * 4)

@@ -11,7 +11,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs import get_config
 from repro.core.distributed import make_distributed_ho_sgd
 from repro.core.ho_sgd import HOSGDConfig
@@ -34,7 +33,7 @@ def main():
     fo, zo = make_distributed_ho_sgd(loss_fn, mesh, ho, opt, model_cfg=cfg,
                                      params_like=params)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fo_j, zo_j = jax.jit(fo), jax.jit(zo)
         opt_state = opt.init(params)
         data = shard_batches(token_batches(cfg.vocab_size, 8, 64), mesh)

@@ -37,9 +37,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core import rounds
-from repro.core.engine import make_engine
+from repro.core.engine import ENGINES, make_engine
 from repro.core.ho_sgd import HOSGDConfig
 from repro.dist import collectives as coll
 from repro.dist.compress import Compressor, compress_tree
@@ -240,7 +239,6 @@ def make_zo_step(
     m: Optional[int] = None,
     fsdp: bool = False,
     param_specs_tree: Any = None,
-    vmap_workers: bool = False,
 ) -> Callable:
     """(t, params, opt_state, batch) -> (params, opt_state, loss).
 
@@ -253,18 +251,16 @@ def make_zo_step(
     The direction algebra itself lives in ``repro.core.engine`` — the
     backend is picked by ``ho.engine`` ('fused' keeps the direction out of
     program buffers; 'pallas' routes through the kernels; 'tree' is the
-    reference; 'flat' packs the tree into one buffer and, with plain SGD on
-    unsharded params, fuses the whole round into two kernel families on the
-    auto-sharded branch) and the params' sharding specs are threaded into
-    the engine
-    so every hash-generated leaf and accumulator carries a sharding
+    reference; 'flat' packs the tree into one buffer and runs one kernel
+    per primitive) and the params' sharding specs are threaded into the
+    engine so every hash-generated leaf and accumulator carries a sharding
     constraint (without one the partitioner is free to replicate the full
     d-dim direction per device — 1.8 TB fp32 for arctic).
 
-    ``vmap_workers`` makes the 0.4.x auto-sharded fallback evaluate the m
-    worker coefficients (and the reconstruction) under one vmap, keeping
-    its HLO O(1) in m — the large-m CPU-rehearsal mode; the default stays
-    unrolled, which is bit-compatible with the single-host reference.
+    ``m`` (default: the mesh's worker count) may be any multiple of the
+    mesh's worker devices: each device then evaluates ``m / devices``
+    workers in-program on consecutive slices of its batch rows — on a 1x1
+    mesh, all m workers of the single-host reference.
 
     With ``fsdp`` params are sharded over the data axis, so a model replica
     (= the paper's "worker") spans (data, model) and the ZO step runs with
@@ -276,8 +272,7 @@ def make_zo_step(
     """
     rnd = rounds.zo_round(loss_fn, ho, opt, m=m)
     return lower_zo_round(rnd, mesh, m=m, fsdp=fsdp,
-                          param_specs_tree=param_specs_tree,
-                          vmap_workers=vmap_workers)
+                          param_specs_tree=param_specs_tree)
 
 
 def lower_zo_round(
@@ -287,42 +282,63 @@ def lower_zo_round(
     m: Optional[int] = None,
     fsdp: bool = False,
     param_specs_tree: Any = None,
-    vmap_workers: bool = False,
 ) -> Callable:
     """Fuse a ZO round's per-worker coefficient evals + scalar all-gather +
-    reconstruction into one program: the partial-auto shard_map path on new
-    jax, the auto-sharded (GSPMD) fallback with the m evals in-program on
-    0.4.x (``repro.compat``)."""
+    reconstruction into one program: a partial-auto ``jax.shard_map``,
+    manual over the worker axes."""
     loss_fn, ho, opt = (rnd.meta["loss_fn"], rnd.meta["ho"], rnd.meta["opt"])
     if fsdp:
         wa = ()
     else:
         wa = worker_axes(mesh)
     # host-side mesh arithmetic: plain ints, never jax arrays
-    m = m or max(1, math.prod(mesh.shape[a] for a in wa))
+    n_dev = max(1, math.prod(mesh.shape[a] for a in wa))
+    m = m or n_dev
+    if m % n_dev:
+        raise ValueError(f"m={m} workers do not divide over the mesh's "
+                         f"{n_dev} worker devices")
+    k = m // n_dev          # workers evaluated in-program on each device
+    # kernel backends run per device: manual over every mesh axis, with
+    # unconstrained (unsharded) leaves
+    per_device = ENGINES[ho.engine].per_device
+    if per_device and wa:
+        sharded = [a for a in mesh.axis_names
+                   if a not in wa and mesh.shape[a] > 1]
+        if sharded:
+            raise ValueError(
+                f"engine {ho.engine!r} runs Pallas kernels per device and "
+                f"cannot shard params over {sharded}; use a mesh whose "
+                f"non-worker axes have size 1, or engine 'fused'")
+    manual = set(mesh.axis_names) if per_device else set(wa)
+    specs = None if per_device else param_specs_tree
 
     def engine_for(params):
-        return make_engine(ho.engine, params, ho.seed,
-                           specs=param_specs_tree, acc_dtype=ho.acc_dtype)
+        return make_engine(ho.engine, params, ho.seed, specs=specs,
+                           acc_dtype=ho.acc_dtype)
 
-    def _scaled(eng, cs, t, vmap_w=False):
-        rec = eng.reconstruct(cs, t, vmap_workers=vmap_w)
+    def _scaled(eng, cs, t):
+        rec = eng.reconstruct(cs, t)
         return jax.tree.map(lambda a: a * (ho.zo_scale / m), rec)
 
     def zo_inner(t, params, batch_local):
         eng = engine_for(params)
-        # worker id from the manual axes
+        # device index from the manual axes; device i evaluates workers
+        # i*k .. i*k+k-1 on consecutive slices of its batch rows (k = 1 on
+        # a mesh with one device per worker)
         idx = jax.lax.axis_index(wa[0])
         if len(wa) == 2:
             idx = idx * mesh.shape[wa[1]] + jax.lax.axis_index(wa[1])
-        c, f0 = eng.zo_coeff(loss_fn, params, batch_local, t,
-                             idx.astype(jnp.uint32), ho.mu)
-        cs = coll.all_gather(c, wa, tag="zo_coeffs")      # (m,) scalars — the
+        workers = (idx.astype(jnp.uint32) * jnp.uint32(k)
+                   + jnp.arange(k, dtype=jnp.uint32))
+        stacked = jax.tree.map(
+            lambda x: x.reshape(k, x.shape[0] // k, *x.shape[1:]), batch_local)
+        cs, f0s = eng.zo_coeffs(loss_fn, params, stacked, t, workers, ho.mu)
+        cs = coll.all_gather(cs, wa, tag="zo_coeffs")     # (m,) scalars — the
         cs = cs.reshape(-1)                               # paper's entire comm
         g_hat = _scaled(eng, cs, t)
         # averaging the monitoring loss is diagnostics, not Algorithm 1's
         # communication — booked as non-payload so measured bytes stay 4*m
-        loss = coll.pmean(f0, wa, tag="loss", payload=False)
+        loss = coll.pmean(jnp.mean(f0s), wa, tag="loss", payload=False)
         return g_hat, loss
 
     def zo_single(t, params, batch):
@@ -339,92 +355,19 @@ def lower_zo_round(
         g_hat = _scaled(eng, cs, t)
         return g_hat, f0
 
-    def zo_auto(t, params, batch):
-        """Auto-sharded (GSPMD) formulation with identical semantics.
-
-        jax 0.4.x's partitioner aborts on collectives inside a partial-auto
-        shard_map (see repro.compat), so on old runtimes the m worker
-        evaluations run in-program over the workers' batch slices and the
-        coefficient exchange is left to GSPMD.  Same math, same directions,
-        same (booked) communication — the m evals serialize in the program
-        instead of running one-per-worker, a documented cost of the
-        fallback, not of the method.  ``vmap_workers`` batches those m
-        evaluations (and the reconstruction) under one vmap so the lowered
-        HLO stays O(1) in m.
-        """
-        for x in jax.tree.leaves(batch):
-            assert x.shape[0] % m == 0, \
-                f"batch {x.shape} not divisible by m={m} workers"
-        eng = engine_for(params)
-        workers = jnp.arange(m, dtype=jnp.uint32)
-        stacked = jax.tree.map(
-            lambda x: x.reshape(m, x.shape[0] // m, *x.shape[1:]), batch)
-        cs, f0s = eng.zo_coeffs(loss_fn, params, stacked, t, workers, ho.mu,
-                                vmap_workers=vmap_workers)
-        cs = coll.note("all_gather", cs, tag="zo_coeffs")
-        g_hat = _scaled(eng, cs, t, vmap_w=vmap_workers)
-        loss = coll.note("pmean", jnp.mean(f0s), tag="loss", payload=False)
-        return g_hat, loss
-
-    # Fused single-buffer path: engine='flat' + plain SGD + unsharded params
-    # on the auto-sharded branch (the kernels run per-device, so sharded
-    # meshes and the shard_map lowering keep the generic reconstruct-then-
-    # opt.apply path — same math, pinned by the equivalence suite).
-    fused_flat = (ho.engine == "flat" and opt.kind == "sgd"
-                  and param_specs_tree is None)
-
-    def zo_auto_flat(t, params, opt_state, batch):
-        """zo_auto semantics with the flat engine's fused kernels: the
-        packed buffer lives across the round, each perturb accumulates the
-        tree-wide ||v||^2 in its own launch, and the reconstruction + SGD
-        (+momentum) commit is one in-place kernel — the update vector never
-        exists in HBM.  Booked communication is identical to ``zo_auto``
-        (4*m coefficient bytes + the non-payload monitoring loss)."""
-        for x in jax.tree.leaves(batch):
-            assert x.shape[0] % m == 0, \
-                f"batch {x.shape} not divisible by m={m} workers"
-        eng = engine_for(params)
-        workers = jnp.arange(m, dtype=jnp.uint32)
-        stacked = jax.tree.map(
-            lambda x: x.reshape(m, x.shape[0] // m, *x.shape[1:]), batch)
-        buf = eng.pack(params)
-        cs, invs, f0s = [], [], []
-        for i in range(m):
-            b_i = jax.tree.map(lambda x: x[i], stacked)
-            f0 = loss_fn(params, b_i)
-            pbuf, ss = eng.fused_perturb_sumsq(buf, t, workers[i], ho.mu)
-            f1 = loss_fn(eng.unpack(pbuf), b_i)
-            cs.append(((eng.dim / ho.mu) * (f1 - f0)).astype(jnp.float32))
-            invs.append(jax.lax.rsqrt(ss + 1e-30))
-            f0s.append(f0)
-        cs = coll.note("all_gather", jnp.stack(cs), tag="zo_coeffs")
-        scaled = cs * jnp.stack(invs) * jnp.float32(ho.zo_scale / m)
-        loss = coll.note("pmean", jnp.mean(jnp.stack(f0s)), tag="loss",
-                         payload=False)
-        momentum = float(opt.hyper["momentum"])
-        mom = eng.pack(opt_state) if momentum else None
-        buf, mom = eng.fused_reconstruct_update(
-            buf, mom, t, workers, scaled, opt.hyper["schedule"](t), momentum)
-        opt_state = eng.unpack(mom, cast=False) if momentum else opt_state
-        return eng.unpack(buf), opt_state, loss
-
     def zo_step(t, params, opt_state, batch):
         if not wa:
             g_hat, loss = zo_single(t, params, batch)
-        elif not compat.HAS_PARTIAL_AUTO_COLLECTIVES:
-            if fused_flat:
-                return zo_auto_flat(t, params, opt_state, batch)
-            g_hat, loss = zo_auto(t, params, batch)
         else:
             params_specs = _replicated_specs(params)
             bspecs = jax.tree.map(
                 lambda x: P(wa, *([None] * (x.ndim - 1))), batch)
-            g_hat, loss = compat.shard_map(
+            g_hat, loss = jax.shard_map(
                 partial(zo_inner, t),
                 mesh=mesh,
                 in_specs=(params_specs, bspecs),
                 out_specs=(params_specs, P()),
-                axis_names=set(wa),
+                axis_names=manual,
                 check_vma=False,
             )(params, batch)
         deltas, opt_state = opt.update(g_hat, opt_state, params, t)
@@ -441,7 +384,6 @@ def make_distributed_ho_sgd(
     model_cfg=None,
     params_like: Any = None,
     compressor: Optional[Compressor] = None,
-    vmap_workers: bool = False,
     compress_mode: str = "per_worker",
     fo_buckets: int = 1,
 ):
@@ -464,8 +406,7 @@ def make_distributed_ho_sgd(
     fo = make_fo_step(loss_fn, mesh, opt, grad_accum=ga, scan_unroll=su,
                       compressor=compressor, seed=ho.seed,
                       compress_mode=compress_mode, buckets=fo_buckets)
-    zo = make_zo_step(loss_fn, mesh, ho, opt, fsdp=fsdp, param_specs_tree=specs,
-                      vmap_workers=vmap_workers)
+    zo = make_zo_step(loss_fn, mesh, ho, opt, fsdp=fsdp, param_specs_tree=specs)
     return fo, zo
 
 

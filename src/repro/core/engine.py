@@ -84,6 +84,11 @@ class DirectionEngine:
     """Base class: shared metadata, norm algebra, and the coefficient eval."""
 
     name = "base"
+    #: the backend runs Pallas kernels, which execute per device: a mesh
+    #: program must call it inside a shard_map that is manual over every
+    #: mesh axis, on unsharded leaves (Mosaic kernels cannot be
+    #: auto-partitioned)
+    per_device = False
 
     def __init__(self, params_like: Any, seed: int, *, specs: Any = None,
                  acc_dtype: Any = "float32", block: int = 4096):
@@ -159,16 +164,10 @@ class DirectionEngine:
         return ((self.dim / mu) * (f1 - f0)).astype(jnp.float32), f0
 
     def zo_coeffs(self, loss_fn: Callable, params: Any, batches: Any, t,
-                  workers: jax.Array, mu: float, *, vmap_workers: bool = False
+                  workers: jax.Array, mu: float
                   ) -> Tuple[jax.Array, jax.Array]:
         """All m workers' coefficients; ``batches`` is worker-stacked
-        (m, B, ...).  ``vmap_workers`` evaluates the m (perturb + loss)
-        pairs under one vmap — HLO O(1) in m, at the cost of materializing
-        one direction leaf per in-flight worker (the CPU-rehearsal trade)."""
-        if vmap_workers:
-            return jax.vmap(
-                lambda w, b: self.zo_coeff(loss_fn, params, b, t, w, mu)
-            )(workers, batches)
+        (m, B, ...)."""
         cs, f0s = [], []
         for i in range(int(workers.shape[0])):
             b_i = jax.tree.map(lambda x: x[i], batches)
@@ -300,6 +299,7 @@ class PallasEngine(DirectionEngine):
     """
 
     name = "pallas"
+    per_device = True
 
     def perturb(self, params, t, worker, scale):
         from repro.kernels import ops  # deferred: keeps core importable early
@@ -343,8 +343,9 @@ class FlatEngine(DirectionEngine):
 
     The parameter tree is packed once into a single contiguous f32 buffer
     with every leaf padded to a multiple of ``block``, so each grid block
-    belongs to exactly one leaf; per-block ``(salt-index, counter-start,
-    valid-lanes, is-bf16)`` metadata is precomputed at construction.  The
+    belongs to exactly one leaf; per-leaf ``(first block, element count,
+    is-bf16)`` tables are precomputed at construction, and the kernels
+    derive each block's counter start and valid lanes from them.  The
     hash identity is unchanged — leaf-local counters from 0, one salt per
     ``(t, worker, leaf)`` — so the algebra matches the other backends.
 
@@ -367,30 +368,27 @@ class FlatEngine(DirectionEngine):
     """
 
     name = "flat"
+    per_device = True
 
     def __init__(self, params_like: Any, seed: int, *, specs: Any = None,
                  acc_dtype: Any = "float32", block: int = 4096):
         super().__init__(params_like, seed, specs=specs, acc_dtype=acc_dtype,
                          block=block)
-        blk_leaf, blk_ctr, blk_nv, blk_bf16 = [], [], [], []
+        starts = []
         self.pad_offsets: List[int] = []   # leaf start in the PACKED buffer
         off = 0
-        for i, n in enumerate(self.sizes):
+        for n in self.sizes:
             self.pad_offsets.append(off)
-            nb = max(1, -(-n // block))    # scalars still occupy one block
-            for b in range(nb):
-                blk_leaf.append(i)
-                blk_ctr.append(b * block)
-                blk_nv.append(min(block, n - b * block))
-            off += nb * block
+            starts.append(off // block)
+            off += max(1, -(-n // block)) * block   # scalars still take a block
         self.padded_dim = off
-        self._blk_leaf = jnp.asarray(blk_leaf, jnp.int32)
-        self._blk_ctr = jnp.asarray(blk_ctr, jnp.uint32)
-        self._blk_nv = jnp.asarray(blk_nv, jnp.int32)
-        self._blk_bf16 = jnp.asarray(
-            [1 if self.dtypes[i] == jnp.bfloat16 else 0 for i in blk_leaf],
-            jnp.int32)
-        self.n_blocks = len(blk_leaf)
+        self.n_blocks = off // block
+        # per-leaf layout tables (first block, element count, is-bf16): the
+        # kernels derive each block's leaf, counter start and valid lanes
+        self._starts = jnp.asarray(starts, jnp.int32)
+        self._sizes = jnp.asarray(self.sizes, jnp.int32)
+        self._bf16 = jnp.asarray(
+            [1 if dt == jnp.bfloat16 else 0 for dt in self.dtypes], jnp.int32)
 
     # ---- packed-buffer layout ------------------------------------------- #
     def pack(self, tree: Any) -> jax.Array:
@@ -415,24 +413,18 @@ class FlatEngine(DirectionEngine):
             outs.append(self._constrain(leaf, i))
         return jax.tree.unflatten(self.treedef, outs)
 
-    def blk_salts(self, t, worker) -> jax.Array:
-        """(n_blocks,) uint32 — each block's leaf salt for (t, worker)."""
-        return jnp.stack(self.salts(t, worker))[self._blk_leaf]
-
-    def blk_salts_multi(self, t, workers) -> jax.Array:
-        """(n_blocks, m) uint32 — per-(block, worker) salts."""
-        m = int(workers.shape[0])
-        return jnp.stack(
-            [self.blk_salts(t, _as_worker(workers[i])) for i in range(m)],
-            axis=1)
+    def leaf_salts(self, t, workers) -> jax.Array:
+        """(m, L) uint32 — per-(worker, leaf) salts."""
+        return jnp.stack([jnp.stack(self.salts(t, _as_worker(workers[i])))
+                          for i in range(int(workers.shape[0]))])
 
     # ---- standard primitives (pack -> one launch -> unpack) -------------- #
     def perturb(self, params, t, worker, scale):
         from repro.kernels import ops  # deferred: keeps core importable early
 
         out = ops.zo_perturb_flat(
-            self.pack(params), self.blk_salts(t, worker), self._blk_ctr,
-            self._blk_nv, scale, block=self.block)
+            self.pack(params), self._starts, self._sizes,
+            jnp.stack(self.salts(t, worker)), scale, block=self.block)
         return self.unpack(out)
 
     def _reconstruct(self, coeffs, t, workers):
@@ -442,8 +434,9 @@ class FlatEngine(DirectionEngine):
         invs = jnp.stack(
             [self.inv_norm(t, _as_worker(workers[i])) for i in range(m)])
         out = ops.zo_reconstruct_flat(
-            self.blk_salts_multi(t, workers), coeffs * invs, self._blk_ctr,
-            self._blk_nv, block=self.block, acc_dtype=str(self.acc_dtype))
+            self.n_blocks, self._starts, self._sizes,
+            self.leaf_salts(t, workers), coeffs * invs, block=self.block,
+            acc_dtype=str(self.acc_dtype))
         return self.unpack(out, cast=False)
 
     # ---- fused step path (buffer stays packed across the round) ---------- #
@@ -454,8 +447,8 @@ class FlatEngine(DirectionEngine):
         from repro.kernels import ops
 
         out, ss = ops.zo_perturb_sumsq(
-            buf, self.blk_salts(t, worker), self._blk_ctr, self._blk_nv, mu,
-            block=self.block)
+            buf, self._starts, self._sizes, jnp.stack(self.salts(t, worker)),
+            mu, block=self.block)
         return out, ss[0]
 
     def fused_reconstruct_update(self, buf: jax.Array, mom, t, workers,
@@ -470,8 +463,8 @@ class FlatEngine(DirectionEngine):
         from repro.kernels import ops
 
         return ops.zo_reconstruct_update(
-            buf, mom, self.blk_salts_multi(t, workers), self._blk_ctr,
-            self._blk_nv, self._blk_bf16, scaled_coeffs, lr,
+            buf, mom, self._starts, self._sizes, self._bf16,
+            self.leaf_salts(t, workers), scaled_coeffs, lr,
             momentum=float(momentum), block=self.block,
             acc_dtype=str(self.acc_dtype))
 

@@ -25,7 +25,7 @@ is now written ONCE as a ``RoundProgram``:
 Consumers (README §RoundProgram):
 
   * ``core.distributed.make_fo_step`` / ``make_zo_step`` LOWER the HO-SGD
-    rounds to the mesh (shard_map or the 0.4.x auto-sharded fallback) —
+    rounds to the mesh (the ZO round as a shard_map) —
     the whole schedule fuses into monolithic jitted programs, bit-identical
     to the pre-IR step functions on the synchronous full-membership path.
   * ``core.baselines`` builds PA/RI/QSGD (and gossip-PA) as round programs

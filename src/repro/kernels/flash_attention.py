@@ -89,14 +89,8 @@ def flash_attention_pallas(
     softcap: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool,
 ) -> jax.Array:
-    # None auto-detects like kernels.ops.INTERPRET (resolved here, not at
-    # import, to avoid a circular import with ops): callers bypassing ops
-    # get interpret mode on CPU and Mosaic on TPU instead of silently
-    # interpreting on real hardware.
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     BH, Sq, hd = q.shape
     Sk = k.shape[1]
     block_q = min(block_q, Sq)

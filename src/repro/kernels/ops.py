@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute in ``interpret=True`` mode (the
-kernel body runs per-block in Python/XLA-CPU); on a real TPU runtime
-``interpret=False`` lowers through Mosaic.  ``INTERPRET`` auto-detects.
+The one place that decides interpret mode: every raw kernel takes
+``interpret`` as a required keyword, and these wrappers pass ``INTERPRET``.
+On a CPU backend the kernel body runs per-block in interpret mode; on a TPU
+it lowers through Mosaic.
 """
 from __future__ import annotations
 
@@ -86,36 +87,39 @@ def zo_reconstruct(n: int, salts, coeffs, offset=0, block: int = 4096,
 
 
 # ---- flat (packed multi-leaf) kernels: one launch for the whole tree ---- #
+# ``starts``/``sizes`` are the per-leaf layout tables of the packed buffer
+# (first block, element count); salts are per leaf, (m, L) for m workers.
 
 @partial(jax.jit, static_argnames=("block",))
-def zo_perturb_flat(x, salts, ctrs, nvalid, scale, block: int = 4096):
-    return zo_k.zo_perturb_flat(x, salts, ctrs, nvalid, scale, block=block,
+def zo_perturb_flat(x, starts, sizes, salts, scale, block: int = 4096):
+    return zo_k.zo_perturb_flat(x, starts, sizes, salts, scale, block=block,
                                 interpret=INTERPRET)
 
 
-@partial(jax.jit, static_argnames=("block", "acc_dtype"))
-def zo_reconstruct_flat(salts, coeffs, ctrs, nvalid, block: int = 4096,
-                        acc_dtype="float32"):
-    return zo_k.zo_reconstruct_flat(salts, coeffs, ctrs, nvalid, block=block,
+@partial(jax.jit, static_argnames=("n_blocks", "block", "acc_dtype"))
+def zo_reconstruct_flat(n_blocks: int, starts, sizes, salts, coeffs,
+                        block: int = 4096, acc_dtype="float32"):
+    return zo_k.zo_reconstruct_flat(n_blocks, starts, sizes, salts, coeffs,
+                                    block=block,
                                     acc_dtype=jnp.dtype(acc_dtype),
                                     interpret=INTERPRET)
 
 
 @partial(jax.jit, static_argnames=("block",))
-def zo_perturb_sumsq(x, salts, ctrs, nvalid, mu, block: int = 4096):
-    return zo_k.zo_perturb_sumsq(x, salts, ctrs, nvalid, mu, block=block,
+def zo_perturb_sumsq(x, starts, sizes, salts, mu, block: int = 4096):
+    return zo_k.zo_perturb_sumsq(x, starts, sizes, salts, mu, block=block,
                                  interpret=INTERPRET)
 
 
 @partial(jax.jit, static_argnames=("momentum", "block", "acc_dtype"),
          donate_argnums=(0, 1))
-def zo_reconstruct_update(p, mom, salts, ctrs, nvalid, bf16_mask, coeffs, lr,
+def zo_reconstruct_update(p, mom, starts, sizes, bf16_mask, salts, coeffs, lr,
                           momentum: float = 0.0, block: int = 4096,
                           acc_dtype="float32"):
     """Fused reconstruct+SGD commit.  ``p``/``mom`` are donated (the kernel
     aliases them in place); when called under an outer jit the donation is
     simply inherited from the caller."""
     return zo_k.zo_reconstruct_update(
-        p, mom, salts, ctrs, nvalid, bf16_mask, coeffs, lr,
+        p, mom, starts, sizes, bf16_mask, salts, coeffs, lr,
         momentum=momentum, block=block, acc_dtype=jnp.dtype(acc_dtype),
         interpret=INTERPRET)

@@ -20,7 +20,8 @@ def rmsnorm_pallas(
     scale: jax.Array,        # (D,)
     eps: float = 1e-6,
     block_rows: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     R, D = x.shape
     block_rows = min(block_rows, R)

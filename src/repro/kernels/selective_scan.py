@@ -8,7 +8,8 @@ O(S*di*n) to O(S*(di + n)) — the memory-roofline win quantified in
 EXPERIMENTS.md §Perf.
 
 Layout: channels tiled (block_d), sequence tiled (block_s, sequential), time
-recurrence is an in-register ``fori_loop`` over the block's steps.
+recurrence is an in-register ``fori_loop`` over tile-aligned slabs of the
+block's steps, each slab's rows walked statically.
 """
 from __future__ import annotations
 
@@ -20,8 +21,13 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# time steps per aligned slab: the (16, 128) tile of a 16-bit array, so a
+# slab load/store at a multiple of it is provably aligned for bf16 and fp32
+_SLAB = 16
+
+
 def _scan_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, o_ref, h_scr,
-                 *, block_s: int):
+                 y_scr, *, block_s: int):
     sb = pl.program_id(2)
 
     @pl.when(sb == 0)
@@ -29,26 +35,29 @@ def _scan_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, o_ref, h_scr,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     A = a_ref[...].astype(jnp.float32)            # (bd, n)
-    Dp = d_ref[...].astype(jnp.float32)           # (bd,)
-    u = u_ref[0].astype(jnp.float32)              # (bs, bd)
-    dt = dt_ref[0].astype(jnp.float32)            # (bs, bd)
-    Bm = b_ref[0].astype(jnp.float32)             # (bs, n)
-    Cm = c_ref[0].astype(jnp.float32)             # (bs, n)
+    Dp = d_ref[0].astype(jnp.float32)             # (bd,)
+    slab = min(_SLAB, block_s)
 
-    def step(t, carry):
-        h = carry                                  # (bd, n)
-        dt_t = jax.lax.dynamic_slice_in_dim(dt, t, 1, 0)[0]   # (bd,)
-        u_t = jax.lax.dynamic_slice_in_dim(u, t, 1, 0)[0]
-        B_t = jax.lax.dynamic_slice_in_dim(Bm, t, 1, 0)[0]    # (n,)
-        C_t = jax.lax.dynamic_slice_in_dim(Cm, t, 1, 0)[0]
-        dA = jnp.exp(dt_t[:, None] * A)                       # (bd, n)
-        dBu = (dt_t * u_t)[:, None] * B_t[None, :]
-        h = dA * h + dBu
-        y_t = jnp.sum(h * C_t[None, :], axis=1) + Dp * u_t    # (bd,)
-        o_ref[0, t, :] = y_t.astype(o_ref.dtype)
+    def slab_step(c, h):                           # h: (bd, n)
+        # Mosaic lowers only tile-aligned dynamic row offsets, so the
+        # recurrence reads a slab of rows at an aligned offset and walks
+        # its rows statically
+        t0 = pl.multiple_of(c * slab, slab)
+        rows = pl.ds(t0, slab)
+        dt_s = dt_ref[0, rows, :].astype(jnp.float32)         # (slab, bd)
+        u_s = u_ref[0, rows, :].astype(jnp.float32)
+        B_s = b_ref[0, rows, :].astype(jnp.float32)           # (slab, n)
+        C_s = c_ref[0, rows, :].astype(jnp.float32)
+        for j in range(slab):
+            dt_t, u_t = dt_s[j], u_s[j]                        # (bd,)
+            dA = jnp.exp(dt_t[:, None] * A)                    # (bd, n)
+            dBu = (dt_t * u_t)[:, None] * B_s[j][None, :]
+            h = dA * h + dBu
+            y_scr[j, :] = jnp.sum(h * C_s[j][None, :], axis=1) + Dp * u_t
+        o_ref[0, rows, :] = y_scr[...].astype(o_ref.dtype)
         return h
 
-    h_scr[...] = jax.lax.fori_loop(0, block_s, step, h_scr[...])
+    h_scr[...] = jax.lax.fori_loop(0, block_s // slab, slab_step, h_scr[...])
 
 
 def selective_scan_pallas(
@@ -61,13 +70,14 @@ def selective_scan_pallas(
     *,
     block_d: int = 256,
     block_s: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     B, S, di = u.shape
     n = A.shape[1]
     block_d = min(block_d, di)
     block_s = min(block_s, S)
     assert di % block_d == 0 and S % block_s == 0
+    assert block_s % min(_SLAB, block_s) == 0, (block_s, _SLAB)
     kern = functools.partial(_scan_kernel, block_s=block_s)
     return pl.pallas_call(
         kern,
@@ -80,9 +90,13 @@ def selective_scan_pallas(
             pl.BlockSpec((1, block_s, n), lambda b, d, s: (b, s, 0)),
             pl.BlockSpec((1, block_s, n), lambda b, d, s: (b, s, 0)),
             pl.BlockSpec((block_d, n), lambda b, d, s: (d, 0)),
-            pl.BlockSpec((block_d,), lambda b, d, s: (d,)),
+            # D as a (1, di) row: a 1-D operand's XLA tiling differs
+            # from the block's
+            pl.BlockSpec((1, block_d), lambda b, d, s: (0, d)),
         ],
         out_specs=pl.BlockSpec((1, block_s, block_d), lambda b, d, s: (b, s, d)),
-        scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32),
+                        pltpu.VMEM((min(_SLAB, block_s), block_d),
+                                   jnp.float32)],
         interpret=interpret,
-    )(u, dt, Bmat, Cmat, A, D)
+    )(u, dt, Bmat, Cmat, A, D.reshape(1, di))

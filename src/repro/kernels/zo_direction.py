@@ -16,10 +16,10 @@ so the direction never exists in HBM:
 The per-leaf kernels above take one ``(salt, offset)`` pair per call, so the
 optimizer hot path launches one kernel per parameter leaf.  The *flat*
 kernels below operate on the whole tree packed into ONE contiguous f32
-buffer with block-aligned leaves, consuming per-BLOCK metadata arrays
-(salt, leaf-local counter start, valid-lane count — built once by
-``repro.core.engine.FlatEngine``), so a full multi-leaf primitive is a
-single kernel launch:
+buffer with block-aligned leaves, consuming per-LEAF tables (first block,
+element count, salt — built once by ``repro.core.engine.FlatEngine``); each
+grid step finds its leaf by scanning the table in scalar memory, so a full
+multi-leaf primitive is a single kernel launch:
 
 * ``zo_perturb_flat``     — one launch for the whole tree's perturbation.
 * ``zo_reconstruct_flat`` — one launch for the whole tree's m-worker
@@ -40,6 +40,13 @@ single kernel launch:
                             via ``input_output_aliases`` (in-place on the
                             donated buffer); the update vector never exists
                             in HBM.
+
+Scalars (salts, offsets, coefficients, learning rate) and the leaf tables
+live in SMEM, the TPU's scalar memory: Mosaic refuses rank-1 blocks of one
+element, and per-block tables of a billion-parameter buffer would not fit
+SMEM's 1 MiB, while per-leaf tables are a few words per leaf.  Reductions
+accumulate into an SMEM scalar output (vector memory cannot take a scalar
+store).
 
 ``offset`` shifts the leaf-local hash counter: the optimizer hashes each
 leaf with its own salt and counters starting at 0, the grid shifts each
@@ -63,7 +70,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.directions import _GOLDEN, _SALT2, _TWO_PI, _XOR2, _uniform01, mix32
+from repro.core.directions import _GOLDEN, _SALT2, _TWO_PI, _XOR2, mix32
+
+
+def _uniform01(bits: jax.Array) -> jax.Array:
+    """``directions._uniform01`` with the cast through int32: exact below
+    2**24, and the only integer-to-float conversion Mosaic accepts (it
+    refuses uint32 -> float32).  Same values as the jnp path, which keeps
+    its own cast: on XLA:CPU the int32 form changes FMA contraction around
+    it, and the kernel tests' bitwise pins compare against that path."""
+    top = (bits >> 8).astype(jnp.int32)
+    return top.astype(jnp.float32) * jnp.float32(2**-24) + jnp.float32(2**-25)
 
 
 def _gauss_block(start: jax.Array, n: int, salt: jax.Array) -> jax.Array:
@@ -80,44 +97,50 @@ def _grid(n: int, block: int) -> int:
     return (n + block - 1) // block
 
 
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole array, scalar memory
+
+
+def _scalars(*xs, dtype) -> jax.Array:
+    """Pack scalars into one (k,) array for an SMEM operand."""
+    return jnp.stack([jnp.asarray(x, dtype).reshape(()) for x in xs])
+
+
 # --------------------------------------------------------------------------- #
 def _sumsq_kernel(meta_ref, o_ref, *, block: int, n: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
-        o_ref[...] = jnp.zeros_like(o_ref)
+        o_ref[0] = jnp.float32(0.0)
 
-    salt = meta_ref[0].astype(jnp.uint32)
-    offset = meta_ref[1].astype(jnp.uint32)
-    g = _gauss_block(offset + jnp.uint32(i * block), block, salt)
+    g = _gauss_block(meta_ref[1] + (i * block).astype(jnp.uint32), block,
+                     meta_ref[0])
     # tail mask: the hash yields (garbage) values for any counter, so lanes
     # past the leaf end must be excluded from the reduction explicitly
     lane = jax.lax.iota(jnp.int32, block) + i * block
     o_ref[0] += jnp.sum(jnp.where(lane < n, g * g, 0.0))
 
 
-def zo_sumsq(n: int, salt, offset=0, block: int = 4096, interpret: bool = True) -> jax.Array:
+def zo_sumsq(n: int, salt, offset=0, *, block: int = 4096,
+             interpret: bool) -> jax.Array:
     """||v_leaf||^2 for a hashed Gaussian leaf of n elements (no HBM input)."""
     block = min(block, n)
-    meta = jnp.asarray([salt, offset], jnp.uint32)
     out = pl.pallas_call(
         functools.partial(_sumsq_kernel, block=block, n=n),
         out_shape=jax.ShapeDtypeStruct((1,), jnp.float32),
         grid=(_grid(n, block),),
-        in_specs=[pl.BlockSpec((2,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((1,), lambda i: (0,)),
+        in_specs=[_SMEM],
+        out_specs=_SMEM,
         interpret=interpret,
-    )(meta)
+    )(_scalars(salt, offset, dtype=jnp.uint32))
     return out[0]
 
 
 # --------------------------------------------------------------------------- #
 def _perturb_kernel(x_ref, meta_ref, scale_ref, o_ref, *, block: int):
     i = pl.program_id(0)
-    salt = meta_ref[0].astype(jnp.uint32)
-    offset = meta_ref[1].astype(jnp.uint32)
-    g = _gauss_block(offset + jnp.uint32(i * block), block, salt)
+    g = _gauss_block(meta_ref[1] + (i * block).astype(jnp.uint32), block,
+                     meta_ref[0])
     x = x_ref[...].astype(jnp.float32)
     o_ref[...] = (x + scale_ref[0] * g).astype(o_ref.dtype)
 
@@ -127,34 +150,31 @@ def zo_perturb(
     salt,
     scale,               # mu * inv_norm (fp32 scalar)
     offset=0,
+    *,
     block: int = 4096,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     n = x.shape[0]
     block = min(block, n)
-    meta = jnp.asarray([salt, offset], jnp.uint32)
     return pl.pallas_call(
         functools.partial(_perturb_kernel, block=block),
         out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
         grid=(_grid(n, block),),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((2,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
+        in_specs=[pl.BlockSpec((block,), lambda i: (i,)), _SMEM, _SMEM],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         interpret=interpret,
-    )(x, meta, jnp.asarray([scale], jnp.float32))
+    )(x, _scalars(salt, offset, dtype=jnp.uint32),
+      _scalars(scale, dtype=jnp.float32))
 
 
 # --------------------------------------------------------------------------- #
 def _reconstruct_kernel(salts_ref, coeffs_ref, off_ref, o_ref, *, block: int,
                         m: int, acc_dtype):
     i = pl.program_id(0)
-    start = off_ref[0].astype(jnp.uint32) + jnp.uint32(i * block)
+    start = off_ref[0] + (i * block).astype(jnp.uint32)
     acc = jnp.zeros((block,), jnp.float32)
     for w in range(m):  # static worker unroll: m gaussians live in registers
-        g = _gauss_block(start, block, salts_ref[w].astype(jnp.uint32))
+        g = _gauss_block(start, block, salts_ref[w])
         acc = acc + coeffs_ref[w] * g
         if acc_dtype != jnp.float32:
             # round to the accumulator dtype after every worker — the exact
@@ -169,9 +189,10 @@ def zo_reconstruct(
     salts: jax.Array,    # (m,) uint32 — per-worker leaf salts
     coeffs: jax.Array,   # (m,) fp32   — c_i * inv_norm_i, pre-scaled
     offset=0,
+    *,
     block: int = 4096,
     acc_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """sum_i coeffs_i * v_i for one flat leaf, one pass, no HBM directions.
 
@@ -185,14 +206,11 @@ def zo_reconstruct(
                           acc_dtype=jnp.dtype(acc_dtype)),
         out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
         grid=(_grid(n, block),),
-        in_specs=[
-            pl.BlockSpec((m,), lambda i: (0,)),
-            pl.BlockSpec((m,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
+        in_specs=[_SMEM, _SMEM, _SMEM],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         interpret=interpret,
-    )(salts, coeffs, jnp.asarray([offset], jnp.uint32))
+    )(salts.astype(jnp.uint32), coeffs.astype(jnp.float32),
+      _scalars(offset, dtype=jnp.uint32))
 
 
 # --------------------------------------------------------------------------- #
@@ -200,106 +218,112 @@ def zo_reconstruct(
 #
 # Packed-buffer convention (repro.core.engine.FlatEngine): every leaf is
 # padded to a multiple of ``block`` so each grid block belongs to exactly
-# ONE leaf; per-block arrays carry that leaf's salt, the block's leaf-local
-# counter start (b * block within its leaf — the same shift the per-leaf
-# grid applies internally), and the number of valid lanes (tail blocks of a
-# leaf mask the padding).  Hash identity is therefore bit-compatible with
-# the per-leaf kernels and the jnp/tree backends: leaf-local counters from
-# 0, one salt per (t, worker, leaf).
+# ONE leaf.  Two per-leaf tables describe the layout — ``starts[l]``, the
+# leaf's first block, and ``sizes[l]``, its element count — and each grid
+# step derives its block's leaf, leaf-local counter start (the same shift
+# the per-leaf grid applies internally) and valid-lane count (tail blocks
+# of a leaf mask the padding) from them.  Hash identity is therefore
+# bit-compatible with the per-leaf kernels and the jnp/tree backends:
+# leaf-local counters from 0, one salt per (t, worker, leaf).
 # --------------------------------------------------------------------------- #
-def _valid_lanes(nv_ref, block: int):
-    return jax.lax.iota(jnp.int32, block) < nv_ref[0]
+def _block_leaf(i, starts_ref, sizes_ref, block: int):
+    """Block i's ``(leaf, leaf-local counter start, valid-lane mask)``."""
+    n_leaves = starts_ref.shape[0]
+    # leaves are laid out in order, so block i belongs to the last leaf
+    # whose first block is <= i (a scalar scan over the SMEM table)
+    leaf = jax.lax.fori_loop(
+        1, n_leaves,
+        lambda l, acc: acc + (starts_ref[l] <= i).astype(jnp.int32),
+        jnp.int32(0))
+    rel = i - starts_ref[leaf]
+    ctr = rel.astype(jnp.uint32) * jnp.uint32(block)
+    valid = jax.lax.iota(jnp.int32, block) < sizes_ref[leaf] - rel * block
+    return leaf, ctr, valid
 
 
-def _perturb_flat_kernel(x_ref, salt_ref, ctr_ref, nv_ref, scale_ref, o_ref,
-                         *, block: int):
-    g = _gauss_block(ctr_ref[0].astype(jnp.uint32), block,
-                     salt_ref[0].astype(jnp.uint32))
+def _perturb_flat_kernel(starts_ref, sizes_ref, salts_ref, scale_ref, x_ref,
+                         o_ref, *, block: int):
+    leaf, ctr, valid = _block_leaf(pl.program_id(0), starts_ref, sizes_ref,
+                                   block)
+    g = _gauss_block(ctr, block, salts_ref[leaf])
     x = x_ref[...]
     # padding lanes carry x through unchanged (zeros stay zeros)
-    o_ref[...] = jnp.where(_valid_lanes(nv_ref, block),
-                           x + scale_ref[0] * g, x)
+    o_ref[...] = jnp.where(valid, x + scale_ref[0] * g, x)
 
 
 def zo_perturb_flat(
     x: jax.Array,        # (P,) packed f32 parameter buffer (block-aligned)
-    salts: jax.Array,    # (nb,) uint32 — per-block leaf salt
-    ctrs: jax.Array,     # (nb,) uint32 — per-block leaf-local counter start
-    nvalid: jax.Array,   # (nb,) int32  — valid lanes per block
+    starts: jax.Array,   # (L,) int32 — each leaf's first block
+    sizes: jax.Array,    # (L,) int32 — each leaf's element count
+    salts: jax.Array,    # (L,) uint32 — each leaf's salt
     scale,               # mu * inv_norm (fp32 scalar, premultiplied)
+    *,
     block: int = 4096,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Whole-tree ``x + scale * v`` in ONE kernel launch (vs one per leaf)."""
-    nb = salts.shape[0]
-    assert x.shape[0] == nb * block, (x.shape, nb, block)
+    nb = x.shape[0] // block
+    assert x.shape[0] == nb * block, (x.shape, block)
+    blk = pl.BlockSpec((block,), lambda i: (i,))
     return pl.pallas_call(
         functools.partial(_perturb_flat_kernel, block=block),
         out_shape=jax.ShapeDtypeStruct((nb * block,), jnp.float32),
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
+        in_specs=[_SMEM, _SMEM, _SMEM, _SMEM, blk],
+        out_specs=blk,
         interpret=interpret,
-    )(x, salts, ctrs, nvalid, jnp.asarray(scale, jnp.float32).reshape(1))
+    )(starts, sizes, salts, _scalars(scale, dtype=jnp.float32), x)
 
 
-def _reconstruct_flat_kernel(salts_ref, coeffs_ref, ctr_ref, nv_ref, o_ref,
-                             *, block: int, m: int, acc_dtype):
-    start = ctr_ref[0].astype(jnp.uint32)
+def _reconstruct_flat_kernel(starts_ref, sizes_ref, salts_ref, coeffs_ref,
+                             o_ref, *, block: int, m: int, acc_dtype):
+    leaf, ctr, valid = _block_leaf(pl.program_id(0), starts_ref, sizes_ref,
+                                   block)
+    n_leaves = starts_ref.shape[0]
     acc = jnp.zeros((block,), jnp.float32)
     for w in range(m):  # static worker unroll: m gaussians live in registers
-        g = _gauss_block(start, block, salts_ref[0, w].astype(jnp.uint32))
+        g = _gauss_block(ctr, block, salts_ref[w * n_leaves + leaf])
         acc = acc + coeffs_ref[w] * g
         if acc_dtype != jnp.float32:
             acc = acc.astype(acc_dtype).astype(jnp.float32)
-    o_ref[...] = jnp.where(_valid_lanes(nv_ref, block), acc, 0.0)
+    o_ref[...] = jnp.where(valid, acc, 0.0)
 
 
 def zo_reconstruct_flat(
-    salts: jax.Array,    # (nb, m) uint32 — per-(block, worker) leaf salts
+    n_blocks: int,
+    starts: jax.Array,   # (L,) int32
+    sizes: jax.Array,    # (L,) int32
+    salts: jax.Array,    # (m, L) uint32 — per-(worker, leaf) salts
     coeffs: jax.Array,   # (m,) fp32 — c_i * inv_norm_i, pre-scaled
-    ctrs: jax.Array,     # (nb,) uint32
-    nvalid: jax.Array,   # (nb,) int32
+    *,
     block: int = 4096,
     acc_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Whole-tree ``sum_i coeffs_i * v_i`` in ONE launch; padding lanes 0."""
-    nb, m = salts.shape
+    m = salts.shape[0]
     return pl.pallas_call(
         functools.partial(_reconstruct_flat_kernel, block=block, m=m,
                           acc_dtype=jnp.dtype(acc_dtype)),
-        out_shape=jax.ShapeDtypeStruct((nb * block,), jnp.float32),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, m), lambda i: (i, 0)),
-            pl.BlockSpec((m,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
-        ],
+        out_shape=jax.ShapeDtypeStruct((n_blocks * block,), jnp.float32),
+        grid=(n_blocks,),
+        in_specs=[_SMEM, _SMEM, _SMEM, _SMEM],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         interpret=interpret,
-    )(salts, coeffs, ctrs, nvalid)
+    )(starts, sizes, salts.reshape(-1), coeffs.astype(jnp.float32))
 
 
-def _perturb_sumsq_kernel(x_ref, salt_ref, ctr_ref, nv_ref, mu_ref,
+def _perturb_sumsq_kernel(starts_ref, sizes_ref, salts_ref, mu_ref, x_ref,
                           o_ref, ss_ref, *, block: int):
     p = pl.program_id(0)          # phase: 0 = accumulate sumsq, 1 = perturb
     i = pl.program_id(1)
 
     @pl.when((p == 0) & (i == 0))
     def _():
-        ss_ref[...] = jnp.zeros_like(ss_ref)
+        ss_ref[0] = jnp.float32(0.0)
 
-    g = _gauss_block(ctr_ref[0].astype(jnp.uint32), block,
-                     salt_ref[0].astype(jnp.uint32))
-    valid = _valid_lanes(nv_ref, block)
+    leaf, ctr, valid = _block_leaf(i, starts_ref, sizes_ref, block)
+    g = _gauss_block(ctr, block, salts_ref[leaf])
 
     @pl.when(p == 0)
     def _():
@@ -319,12 +343,13 @@ def _perturb_sumsq_kernel(x_ref, salt_ref, ctr_ref, nv_ref, mu_ref,
 
 def zo_perturb_sumsq(
     x: jax.Array,        # (P,) packed f32 parameter buffer (block-aligned)
-    salts: jax.Array,    # (nb,) uint32 — per-block leaf salt
-    ctrs: jax.Array,     # (nb,) uint32
-    nvalid: jax.Array,   # (nb,) int32
+    starts: jax.Array,   # (L,) int32
+    sizes: jax.Array,    # (L,) int32
+    salts: jax.Array,    # (L,) uint32
     mu,                  # smoothing parameter (fp32 scalar; NOT premultiplied)
+    *,
     block: int = 4096,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple:
     """Fused ``(x + mu * rsqrt(sum v^2) * v, sum v^2)`` in one launch.
 
@@ -341,48 +366,44 @@ def zo_perturb_sumsq(
     point may differ from the per-primitive path in the last ulp — the
     fused-step seam documented in README §DirectionEngine.
     """
-    nb = salts.shape[0]
-    assert x.shape[0] == nb * block, (x.shape, nb, block)
+    nb = x.shape[0] // block
+    assert x.shape[0] == nb * block, (x.shape, block)
     # phase 0 never consumes x / the output block: pin both to block 0
     # (p * i) so no extra HBM pass happens during accumulation; phase 1
     # rewrites block 0 first, so the phase-0 garbage write never survives.
+    blk = pl.BlockSpec((block,), lambda p, i: (p * i,))
     return pl.pallas_call(
         functools.partial(_perturb_sumsq_kernel, block=block),
         out_shape=(jax.ShapeDtypeStruct((nb * block,), jnp.float32),
                    jax.ShapeDtypeStruct((1,), jnp.float32)),
         grid=(2, nb),
-        in_specs=[
-            pl.BlockSpec((block,), lambda p, i: (p * i,)),
-            pl.BlockSpec((1,), lambda p, i: (i,)),
-            pl.BlockSpec((1,), lambda p, i: (i,)),
-            pl.BlockSpec((1,), lambda p, i: (i,)),
-            pl.BlockSpec((1,), lambda p, i: (0,)),
-        ],
-        out_specs=(pl.BlockSpec((block,), lambda p, i: (p * i,)),
-                   pl.BlockSpec((1,), lambda p, i: (0,))),
+        in_specs=[_SMEM, _SMEM, _SMEM, _SMEM, blk],
+        out_specs=(blk, _SMEM),
         interpret=interpret,
-    )(x, salts, ctrs, nvalid, jnp.asarray(mu, jnp.float32).reshape(1))
+    )(starts, sizes, salts, _scalars(mu, dtype=jnp.float32), x)
 
 
-def _reconstruct_update_kernel(p_ref, *refs, block: int, m: int, acc_dtype,
-                               momentum: float, use_momentum: bool):
+def _reconstruct_update_kernel(starts_ref, sizes_ref, bf16_ref, salts_ref,
+                               coeffs_ref, lr_ref, p_ref, *refs, block: int,
+                               m: int, acc_dtype, momentum: float,
+                               use_momentum: bool):
     if use_momentum:
-        (v_ref, salts_ref, ctr_ref, nv_ref, bf16_ref, coeffs_ref, lr_ref,
-         po_ref, vo_ref) = refs
+        v_ref, po_ref, vo_ref = refs
     else:
-        (salts_ref, ctr_ref, nv_ref, bf16_ref, coeffs_ref, lr_ref,
-         po_ref) = refs
-    start = ctr_ref[0].astype(jnp.uint32)
+        (po_ref,) = refs
+    leaf, ctr, valid = _block_leaf(pl.program_id(0), starts_ref, sizes_ref,
+                                   block)
+    n_leaves = starts_ref.shape[0]
     acc = jnp.zeros((block,), jnp.float32)
     for w in range(m):  # static worker unroll: m gaussians live in registers
-        g = _gauss_block(start, block, salts_ref[0, w].astype(jnp.uint32))
+        g = _gauss_block(ctr, block, salts_ref[w * n_leaves + leaf])
         acc = acc + coeffs_ref[w] * g
         if acc_dtype != jnp.float32:
             # round after every worker — the exact semantics of the
             # DirectionEngine accumulators (bit-identical under bf16 acc)
             acc = acc.astype(acc_dtype).astype(jnp.float32)
     # padding lanes contribute nothing: params/momentum padding stays 0
-    acc = jnp.where(_valid_lanes(nv_ref, block), acc, 0.0)
+    acc = jnp.where(valid, acc, 0.0)
     # optimizers.sgd computes deltas = -lr * v and apply_deltas adds them;
     # mirror that expression shape (p + (-lr)*v, not p - lr*v) so XLA's FMA
     # contraction matches the unfused path bit-for-bit
@@ -395,24 +416,32 @@ def _reconstruct_update_kernel(p_ref, *refs, block: int, m: int, acc_dtype,
     else:
         p_new = p_ref[...] + neg_lr * acc
     # leaves stored in bf16 round-trip through their dtype on commit, the
-    # apply_deltas semantics (per-block flag: each block is one leaf's)
-    p_bf16 = p_new.astype(jnp.bfloat16).astype(jnp.float32)
-    po_ref[...] = jnp.where(bf16_ref[0] != 0, p_bf16, p_new)
+    # apply_deltas semantics (per-leaf flag)
+    is_bf16 = bf16_ref[leaf] != 0
+
+    @pl.when(is_bf16)
+    def _():
+        po_ref[...] = p_new.astype(jnp.bfloat16).astype(jnp.float32)
+
+    @pl.when(jnp.logical_not(is_bf16))
+    def _():
+        po_ref[...] = p_new
 
 
 def zo_reconstruct_update(
     p: jax.Array,                  # (P,) packed f32 params (donated, aliased)
     mom,                           # (P,) packed f32 momentum, or None
-    salts: jax.Array,              # (nb, m) uint32
-    ctrs: jax.Array,               # (nb,) uint32
-    nvalid: jax.Array,             # (nb,) int32
-    bf16_mask: jax.Array,          # (nb,) int32 — 1 where the leaf is bf16
+    starts: jax.Array,             # (L,) int32
+    sizes: jax.Array,              # (L,) int32
+    bf16_mask: jax.Array,          # (L,) int32 — 1 where the leaf is bf16
+    salts: jax.Array,              # (m, L) uint32
     coeffs: jax.Array,             # (m,) fp32 — fully pre-scaled
     lr,                            # learning rate (fp32 scalar)
     momentum: float = 0.0,
+    *,
     block: int = 4096,
     acc_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fused reconstruct + SGD(+momentum) commit: the update vector never
     exists in HBM.
@@ -425,42 +454,28 @@ def zo_reconstruct_update(
     ``(p', mom')`` (``mom'`` is None when ``mom`` is None — the
     momentum-free optimizer carries no state buffer).
     """
-    nb, m = salts.shape
-    assert p.shape[0] == nb * block, (p.shape, nb, block)
+    m = salts.shape[0]
+    nb = p.shape[0] // block
+    assert p.shape[0] == nb * block, (p.shape, block)
     use_momentum = mom is not None
     kern = functools.partial(
         _reconstruct_update_kernel, block=block, m=m,
         acc_dtype=jnp.dtype(acc_dtype), momentum=float(momentum),
         use_momentum=use_momentum)
     blk = pl.BlockSpec((block,), lambda i: (i,))
-    meta_specs = [
-        pl.BlockSpec((1, m), lambda i: (i, 0)),
-        pl.BlockSpec((1,), lambda i: (i,)),
-        pl.BlockSpec((1,), lambda i: (i,)),
-        pl.BlockSpec((1,), lambda i: (i,)),
-        pl.BlockSpec((m,), lambda i: (0,)),
-        pl.BlockSpec((1,), lambda i: (0,)),
-    ]
-    lr_arr = jnp.asarray(lr, jnp.float32).reshape(1)
+    scalars = (starts, sizes, bf16_mask, salts.reshape(-1),
+               coeffs.astype(jnp.float32), _scalars(lr, dtype=jnp.float32))
+    bufs = (p, mom) if use_momentum else (p,)
     shape = jax.ShapeDtypeStruct((nb * block,), jnp.float32)
-    if use_momentum:
-        p_out, v_out = pl.pallas_call(
-            kern,
-            out_shape=(shape, shape),
-            grid=(nb,),
-            in_specs=[blk, blk] + meta_specs,
-            out_specs=(blk, blk),
-            input_output_aliases={0: 0, 1: 1},   # in-place: read+write once
-            interpret=interpret,
-        )(p, mom, salts, ctrs, nvalid, bf16_mask, coeffs, lr_arr)
-        return p_out, v_out
-    p_out = pl.pallas_call(
+    n_in = len(scalars)
+    out = pl.pallas_call(
         kern,
-        out_shape=shape,
+        out_shape=(shape,) * len(bufs),
         grid=(nb,),
-        in_specs=[blk] + meta_specs,
-        out_specs=blk,
-        input_output_aliases={0: 0},             # in-place: read+write once
+        in_specs=[_SMEM] * n_in + [blk] * len(bufs),
+        out_specs=(blk,) * len(bufs),
+        # in place: params (and momentum) are read and written once
+        input_output_aliases={n_in + k: k for k in range(len(bufs))},
         interpret=interpret,
-    )(p, salts, ctrs, nvalid, bf16_mask, coeffs, lr_arr)
-    return p_out, None
+    )(*scalars, *bufs)
+    return (out[0], out[1]) if use_momentum else (out[0], None)

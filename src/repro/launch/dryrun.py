@@ -21,7 +21,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 
-from repro import compat
 from repro.configs import (
     ARCH_IDS, SHAPES, config_for_shape, get_config, shape_applicable,
 )
@@ -30,7 +29,7 @@ from repro.core.distributed import make_fo_step, make_zo_step
 from repro.core.ho_sgd import HOSGDConfig
 from repro.dist.sharding import param_specs
 from repro.launch import hlo
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import auto_mesh, make_production_mesh
 from repro.launch.specs import input_specs
 from repro.models import transformer as T
 from repro.opt.optimizers import const_schedule, sgd
@@ -139,7 +138,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, step: str,
     if tm:
         dims = tuple(int(x) for x in tm.split("x"))
         axes = ("pod", "data", "model") if len(dims) == 3 else ("data", "model")
-        mesh = jax.make_mesh(dims, axes)
+        mesh = auto_mesh(dims, axes)
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     p = cfg.pattern_period
@@ -148,7 +147,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, step: str,
                params=cfg.param_count(), params_active=cfg.param_count(True),
                model_flops=model_flops(cfg, shape))
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered, compiled, t_lower, t_compile = lower_compile(cfg, shape, mesh, step)
         rec["lower_s"] = round(t_lower, 2)
         rec["compile_s"] = round(t_compile, 2)

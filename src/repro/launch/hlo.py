@@ -168,8 +168,6 @@ def extrapolate(c1: float, c2: float, n_groups: int) -> float:
 
 def cost_summary(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):   # jax 0.4.x: one dict per computation
-        ca = ca[0] if ca else {}
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),

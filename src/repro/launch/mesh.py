@@ -7,19 +7,31 @@ never touches jax device state; the dry-run sets
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto.
+
+    jax 0.9 makes Explicit axes by default; this code shards through GSPMD
+    (``dist.sharding.param_specs`` + ``with_sharding_constraint``), which
+    needs Auto axes — on Explicit ones the first gather of a replicated
+    embedding by a sharded batch raises ``ShardingTypeError``.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 4, model: int = 2, pod: int = 0):
     """Small mesh for CI subprocess tests (needs >= data*model*max(pod,1) devices)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return auto_mesh((pod, data, model), ("pod", "data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants used by the roofline analysis.

@@ -10,12 +10,12 @@ from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from repro import compat
 from repro.checkpoint import save as ckpt_save
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ModelConfig
@@ -27,17 +27,22 @@ from repro.data import shard_batches, token_batches
 from repro.dist import CommLedger, get_compressor
 from repro.dist.sharding import named, param_specs, n_workers
 from repro.launch.mesh import make_test_mesh
+from repro.launch.xla import use_compile_cache
 from repro.metrics import CSVLogger, comm_report
 from repro.models import transformer as T
 from repro.opt.optimizers import sgd, const_schedule
 
 
-def size_override(cfg: ModelConfig, preset: str) -> ModelConfig:
-    """Depth/width presets so examples fit the local device."""
+def size_override(cfg: ModelConfig, preset: str, layers: int = 0) -> ModelConfig:
+    """Depth/width presets so examples fit the local device.
+
+    ``layers`` (CLI ``--layers``) then cuts the depth alone and keeps every
+    width; it must be a multiple of the config's ``pattern_period``.
+    """
     if preset == "full":
-        return cfg
-    if preset == "100m":
-        return cfg.with_(
+        out = cfg
+    elif preset == "100m":
+        out = cfg.with_(
             n_layers=max(cfg.pattern_period * 4, 8), d_model=768,
             n_heads=12, n_kv_heads=max(1, min(cfg.n_kv_heads, 4)),
             head_dim=64, d_ff=2048, dense_d_ff=min(cfg.dense_d_ff, 2048),
@@ -45,15 +50,26 @@ def size_override(cfg: ModelConfig, preset: str) -> ModelConfig:
             n_experts=min(cfg.n_experts, 8), dt_rank=48,
             dtype="float32",
         )
-    if preset == "smoke":
-        return cfg.reduced()
-    raise ValueError(preset)
+    elif preset == "smoke":
+        out = cfg.reduced()
+    else:
+        raise ValueError(preset)
+    if layers:
+        if layers % out.pattern_period:
+            raise ValueError(
+                f"--layers {layers} is not a multiple of {out.name}'s layer "
+                f"pattern period {out.pattern_period}")
+        out = out.with_(n_layers=layers)
+    return out
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b", choices=ARCH_IDS)
     ap.add_argument("--reduce", default="smoke", choices=["full", "100m", "smoke"])
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to this many layers, keeping every "
+                         "width (0 = the preset's depth)")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--tau", type=int, default=8)
     ap.add_argument("--tau-schedule", default=None,
@@ -82,8 +98,8 @@ def main(argv=None):
                     choices=["tree", "fused", "pallas", "flat"],
                     help="DirectionEngine backend for the ZO direction "
                          "algebra (repro.core.engine); 'flat' packs the "
-                         "tree into one buffer and fuses the ZO round for "
-                         "plain SGD")
+                         "tree into one buffer and runs one kernel per "
+                         "primitive")
     ap.add_argument("--fo-buckets", type=int, default=1,
                     help="chunk the FO gradient all-reduce into this many "
                          "independently-reducible buckets (bit-identical "
@@ -97,8 +113,44 @@ def main(argv=None):
                     help="write a wall-clock Perfetto trace: one span per "
                          "jitted FO/ZO step (ledger bytes attached) plus a "
                          "cumulative received-bytes counter")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+@dataclass
+class Trainer:
+    """One configured training run: what ``setup`` builds and ``run`` drives.
+
+    ``jitted`` holds the bare jitted FO/ZO step programs (for lowering and
+    inspection); ``steps`` holds the same programs wrapped by ``ledger``.
+    """
+    args: argparse.Namespace
+    cfg: ModelConfig
+    mesh: Any
+    m: int
+    d: int
+    leaf_dims: List[int]
+    codec: Any
+    params: Any
+    opt_state: Any
+    ledger: CommLedger
+    jitted: Dict[str, Callable]
+    steps: Dict[str, Callable]
+    tau_sched: Optional[Callable[[int], int]]
+
+    def comm_lines(self) -> List[str]:
+        """Measured (ledger) vs analytic communication lines."""
+        # dense FO exchange moves gradients in the param dtype (fp32
+        # accumulator when grad_accum microbatches); ZO coefficients are
+        # always fp32
+        grad_bytes = (4 if self.cfg.grad_accum > 1
+                      else jnp.dtype(self.cfg.dtype).itemsize)
+        return comm_report(self.ledger, d=self.d, m=self.m,
+                           tau=self.args.tau, codec=self.codec,
+                           leaf_dims=self.leaf_dims, grad_bytes=grad_bytes)
+
+
+def setup(args: argparse.Namespace) -> Trainer:
+    """Mesh, model, HO-SGD step programs and device-resident state."""
     if args.xla_overlap:
         # must land before the first device query initializes the backend
         from repro.launch.xla import enable_collective_overlap
@@ -108,11 +160,13 @@ def main(argv=None):
     mesh = make_test_mesh(data=data_ax, model=args.model_axis)
     m = n_workers(mesh)
 
-    cfg = size_override(get_config(args.arch), args.reduce)
+    cfg = size_override(get_config(args.arch), args.reduce, args.layers)
     if cfg.frontend != "none":
         raise SystemExit("use examples/ drivers for frontend archs")
-    print(f"arch={cfg.name} params={cfg.param_count():,} mesh={dict(mesh.shape)} "
-          f"workers={m}")
+    print(f"arch={cfg.name} layers={cfg.n_layers} d_model={cfg.d_model} "
+          f"heads={cfg.n_heads}x{cfg.head_dim} d_ff={cfg.d_ff} "
+          f"vocab={cfg.vocab_size} params={cfg.param_count():,} "
+          f"mesh={dict(mesh.shape)} workers={m}")
 
     params = T.init_model(jax.random.key(args.seed), cfg)
     loss_fn = lambda p, b: T.loss_fn(cfg, p, b)
@@ -135,14 +189,27 @@ def main(argv=None):
     if tau_sched is not None and args.tau < 2:
         raise SystemExit("--tau-schedule needs --tau >= 2 (the ZO seed map)")
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = jax.device_put(params, named(mesh, param_specs(cfg, params, mesh)))
         opt_state = opt.init(params)
-        ledger = CommLedger()
-        fo_j = ledger.wrap("fo", jax.jit(fo))
-        zo_j = ledger.wrap("zo", jax.jit(zo))
+    ledger = CommLedger()
+    jitted = {"fo": jax.jit(fo), "zo": jax.jit(zo)}
+    steps = {name: ledger.wrap(name, fn) for name, fn in jitted.items()}
+    return Trainer(args, cfg, mesh, m, d, leaf_dims, codec, params, opt_state,
+                   ledger, jitted, steps, tau_sched)
 
-        host = token_batches(cfg.vocab_size, args.batch, args.seq, seed=args.seed)
+
+def run(tr: Trainer, on_step: Optional[Callable] = None) -> float:
+    """The HO-SGD loop; returns the last step's loss.
+
+    ``on_step(t, name, loss, dt, params, batch)`` is called after every
+    step with the step's ("fo" or "zo") blocking wall time ``dt`` and the
+    updated device-resident params.
+    """
+    args = tr.args
+    with jax.set_mesh(tr.mesh):
+        host = token_batches(tr.cfg.vocab_size, args.batch, args.seq,
+                             seed=args.seed)
         since_fo = 0
         tracer = None
         if args.trace:
@@ -151,26 +218,26 @@ def main(argv=None):
         with CSVLogger(args.log,
                        ["step", "order", "loss", "dt", "comm_bytes"]) as logger:
             t_prev = time.perf_counter()
-            for t, batch in zip(range(args.steps), shard_batches(host, mesh)):
-                if tau_sched is None:
+            for t, batch in zip(range(args.steps), shard_batches(host, tr.mesh)):
+                if tr.tau_sched is None:
                     is_fo, t_step = t % args.tau == 0, t
                 else:
                     is_fo, t_step, since_fo = adaptive_tau_decision(
-                        t, since_fo, tau_sched(t), args.tau)
+                        t, since_fo, tr.tau_sched(t), args.tau)
                 name = "fo" if is_fo else "zo"
-                step = fo_j if is_fo else zo_j
+                step = tr.steps[name]
                 t0 = time.perf_counter()
                 if tracer is not None:
                     with tracer.span("compute", "train", name=f"{name}/{t}") as sp:
-                        params, opt_state, loss = step(jnp.int32(t_step),
-                                                       params, opt_state, batch)
+                        tr.params, tr.opt_state, loss = step(
+                            jnp.int32(t_step), tr.params, tr.opt_state, batch)
                         loss = float(loss)       # blocks: dispatch is async
-                        sp.nbytes = ledger.bytes_per_step(name)
+                        sp.nbytes = tr.ledger.bytes_per_step(name)
                     tracer.counter(tracer.now(), "train", "ledger_bytes",
-                                   ledger.total_bytes())
+                                   tr.ledger.total_bytes())
                 else:
-                    params, opt_state, loss = step(jnp.int32(t_step), params,
-                                                   opt_state, batch)
+                    tr.params, tr.opt_state, loss = step(
+                        jnp.int32(t_step), tr.params, tr.opt_state, batch)
                     loss = float(loss)           # blocks: dispatch is async
                 dt_step = time.perf_counter() - t0
                 if t % 10 == 0 or t == args.steps - 1:
@@ -179,28 +246,33 @@ def main(argv=None):
                           f"loss={loss:.4f} dt={now - t_prev:.2f}s")
                     t_prev = now
                 logger.log(step=t, order=int(is_fo), loss=loss, dt=dt_step,
-                           comm_bytes=ledger.bytes_per_step(name))
+                           comm_bytes=tr.ledger.bytes_per_step(name))
+                if on_step is not None:
+                    on_step(t, name, loss, dt_step, tr.params, batch)
             if args.ckpt:
                 if tracer is not None:
                     with tracer.span("checkpoint", "train", name="ckpt_save"):
                         path = ckpt_save(args.ckpt, args.steps,
-                                         jax.device_get(params))
+                                         jax.device_get(tr.params))
                 else:
                     path = ckpt_save(args.ckpt, args.steps,
-                                     jax.device_get(params))
+                                     jax.device_get(tr.params))
                 print("checkpoint:", path)
         if tracer is not None:
             from repro.obs import write_trace
-            write_trace(args.trace, tracer, title=f"train:{cfg.name}")
+            write_trace(args.trace, tracer, title=f"train:{tr.cfg.name}")
             print(f"wrote trace {args.trace} ({len(tracer.spans)} spans)")
-    # dense FO exchange moves gradients in the param dtype (fp32 accumulator
-    # when grad_accum microbatches); ZO coefficients are always fp32
-    grad_bytes = 4 if cfg.grad_accum > 1 else jnp.dtype(cfg.dtype).itemsize
-    for line in comm_report(ledger, d=d, m=m, tau=args.tau, codec=codec,
-                            leaf_dims=leaf_dims, grad_bytes=grad_bytes):
+    return loss
+
+
+def main(argv=None):
+    use_compile_cache()
+    tr = setup(parse_args(argv))
+    loss = run(tr)
+    for line in tr.comm_lines():
         print(line)
-    print("done; final loss", float(loss))
-    return float(loss)
+    print("done; final loss", loss)
+    return loss
 
 
 if __name__ == "__main__":

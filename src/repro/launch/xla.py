@@ -1,4 +1,6 @@
-"""XLA_FLAGS composition — append, never clobber.
+"""Process-level XLA setup: XLA_FLAGS composition and the compile cache.
+
+XLA_FLAGS composition — append, never clobber.
 
 Every launcher that needs an XLA flag (the dryrun's forced host device
 count, the async-collective overlap flags below) must COMPOSE with whatever
@@ -11,7 +13,11 @@ launchers call these helpers at the top of ``main()``.
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Iterable, Sequence, Tuple
+
+#: the checkout's root (``src/repro/launch/xla.py`` -> three levels up)
+CHECKOUT = Path(__file__).resolve().parents[3]
 
 #: the async-collective / latency-hiding scheduler set (SNIPPETS §3 idiom):
 #: lets XLA run each bucket of the chunked flat-gradient reduce
@@ -56,3 +62,20 @@ def enable_collective_overlap() -> str:
     (``--xla-overlap`` in ``launch.train``), composing with — never
     replacing — whatever XLA_FLAGS the user exported."""
     return append_xla_flags(OVERLAP_FLAGS)
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and this
+    sets nothing.  Otherwise the cache goes to ``<checkout>/.jax_cache`` (a
+    fixed path: the path is part of the cache key).  Call it at the start
+    of an entry point, before the first compile — never at import.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -89,10 +89,11 @@ def _attend_seq(cfg: ModelConfig, q, k, v, positions, window) -> jax.Array:
     if cfg.use_pallas:
         # kernel path: needs one static window across layers (or all-full)
         ws = set(cfg.layer_windows())
-        if len(ws) == 1:
-            out = _flash_kernel_call(cfg, q, k, v, causal, next(iter(ws)))
-            if out is not None:
-                return out
+        if len(ws) != 1:
+            raise ValueError(
+                f"use_pallas needs one static window across layers; "
+                f"{cfg.name} has {sorted(ws, key=str)}")
+        return _flash_kernel_call(cfg, q, k, v, causal, next(iter(ws)))
     chunk = cfg.attn_chunk
     if chunk:
         while S % chunk:
@@ -116,10 +117,13 @@ def _attend_seq(cfg: ModelConfig, q, k, v, positions, window) -> jax.Array:
 
 
 def _flash_kernel_call(cfg: ModelConfig, q, k, v, causal, w_static):
-    """Dispatch to the Pallas flash-attention kernel when shapes allow."""
+    """Dispatch to the Pallas flash-attention kernel (blocks of 128 or 64
+    positions, so the sequence must be a multiple of 64)."""
     S = q.shape[1]
     if S % 128 and S % 64:
-        return None  # fall back to the jnp path for unaligned smoke shapes
+        raise ValueError(
+            f"use_pallas needs a sequence length that is a multiple of 64; "
+            f"got {S}")
     from repro.kernels import ops
     block = 128 if S % 128 == 0 else 64
     out = ops.flash_attention(

@@ -604,8 +604,8 @@ def _ho_family(
 ) -> SimMethod:
     """HO-SGD spectrum: the round program (``rounds.ho_sgd_program``) plus
     its monolithic lowering to the real distributed step programs (1x1
-    mesh, ``m`` simulated workers in-program — the 0.4.x auto-sharded ZO
-    path), wrapped in a ``CommLedger`` so costs_for reads measured bytes.
+    mesh, the ZO step evaluating all ``m`` simulated workers in-program on
+    its one device), wrapped in a ``CommLedger`` so costs_for reads measured bytes.
     ``overlap_buckets > 1`` attaches a ``rounds.Overlap`` spec to both round
     kinds — the sim prices the exposed comm tail, the lowering chunks the
     gradient reduce, bytes stay bit-identical."""

@@ -11,18 +11,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_config
 from repro.core.distributed import make_distributed_ho_sgd
 from repro.core.ho_sgd import HOSGDConfig, make_ho_sgd
 from repro.dist.sharding import batch_specs, named, param_specs
+from repro.launch.mesh import auto_mesh
 from repro.models import transformer as T
 from repro.opt.optimizers import const_schedule, sgd
 
 
 def main():
     assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     cfg = get_config("qwen3-14b").reduced()
     params = T.init_model(jax.random.key(0), cfg)
     loss_fn = lambda p, b: T.loss_fn(cfg, p, b)
@@ -37,7 +37,7 @@ def main():
     labels = np.concatenate([toks[:, 1:], -np.ones((8, 1), np.int32)], 1)
     batch = {"tokens": toks, "labels": labels}
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params_d = jax.device_put(params, named(mesh, param_specs(cfg, params, mesh)))
         batch_d = jax.device_put(batch, named(mesh, batch_specs(mesh, batch)))
         opt_state = opt.init(params_d)

@@ -11,11 +11,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..", "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro import compat
 from repro.core.distributed import make_distributed_ho_sgd
 from repro.core.ho_sgd import HOSGDConfig
 from repro.dist import CommLedger, get_compressor
 from repro.dist.sharding import batch_specs, n_workers, named
+from repro.launch.mesh import auto_mesh
 from repro.opt.optimizers import const_schedule, sgd
 
 
@@ -31,7 +31,7 @@ def run(mesh, d, compressor=None):
                                      compressor=compressor)
     ledger = CommLedger()
     fo_j, zo_j = ledger.wrap("fo", jax.jit(fo)), ledger.wrap("zo", jax.jit(zo))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = {"x": jnp.zeros((d,), jnp.float32)}
         state = opt.init(params)
         batch = {"t": jnp.ones((8 * m, d), jnp.float32)}
@@ -45,7 +45,7 @@ def run(mesh, d, compressor=None):
 
 def main():
     assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     d = 4096
 
     ledger, m = run(mesh, d)

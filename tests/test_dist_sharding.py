@@ -14,11 +14,13 @@ from repro.configs import get_config
 from repro.dist.sharding import (
     batch_specs, cache_specs, n_workers, param_specs, worker_axes,
 )
+from repro.launch.mesh import auto_mesh
 from repro.models import transformer as T
 
 
 def mesh_of(*axes):
-    return AbstractMesh(tuple(axes))
+    names, sizes = zip(*axes)
+    return AbstractMesh(sizes, names)
 
 
 POD_MESH = mesh_of(("data", 16), ("model", 16))
@@ -127,14 +129,15 @@ def test_cache_specs_decode_and_long_context():
     specs = cache_specs(cfg, POD_MESH, caches, seq_sharded=False)
     # (L, B, S, KV, hd): batch over workers; kv-heads over model when they
     # divide, else head_dim
+    # (a one-axis tuple entry normalizes to the bare axis name)
     kspec = specs["k"]
-    assert kspec[1] == ("data",)
+    assert kspec[1] == "data"
     assert "model" in (kspec[3] if len(kspec) > 3 else None,
                        kspec[4] if len(kspec) > 4 else None)
     # long_500k: sequence carries the worker axes, batch=1 replicated
     long = jax.eval_shape(lambda: T.init_caches(cfg, 1, 1 << 19, jnp.bfloat16))
     specs = cache_specs(cfg, POD_MESH, long, seq_sharded=True)
-    assert specs["k"][2] == ("data",)
+    assert specs["k"][2] == "data"
     assert len(specs["k"]) < 2 or specs["k"][1] is None
 
 
@@ -142,15 +145,15 @@ def test_cache_specs_ssm():
     cfg = get_config("falcon-mamba-7b")
     caches = jax.eval_shape(lambda: T.init_caches(cfg, 128, 1024, jnp.bfloat16))
     specs = cache_specs(cfg, POD_MESH, caches, seq_sharded=False)
-    assert specs["conv"][1] == ("data",) and specs["conv"][3] == "model"
-    assert specs["ssm"][1] == ("data",) and specs["ssm"][2] == "model"
+    assert specs["conv"][1] == "data" and specs["conv"][3] == "model"
+    assert specs["ssm"][1] == "data" and specs["ssm"][2] == "model"
 
 
 @pytest.mark.skipif(jax.device_count() < 8,
                     reason="needs the CI 8-device tier-1 run")
 def test_worker_axes_real_mesh():
     """The spec contract on a real multi-device mesh (CI forces 8 devices)."""
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = auto_mesh((4, 2), ("data", "model"))
     assert worker_axes(mesh) == ("data",) and n_workers(mesh) == 4
     cfg = get_config("gemma2-2b").reduced()
     params = abstract_params(cfg)

@@ -221,8 +221,8 @@ def test_hot_path_zo_steps_identical_across_engines(engine):
 
 
 def test_zo_step_engines_agree_on_1x1_mesh():
-    """distributed make_zo_step (auto fallback) agrees across backends."""
-    from repro import compat
+    """distributed make_zo_step (the shard_map lowering) agrees across
+    backends."""
     from repro.core.distributed import make_zo_step
     from repro.launch.mesh import make_test_mesh
     from repro.opt.optimizers import const_schedule, sgd
@@ -235,7 +235,7 @@ def test_zo_step_engines_agree_on_1x1_mesh():
     batch = {"t": jnp.ones((4, d), jnp.float32)}
     mesh = make_test_mesh(data=1, model=1)
     outs = {}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name in ("tree", "fused", "pallas"):
             ho = HOSGDConfig(tau=1 << 30, mu=1e-3, m=2, lr=0.05,
                              zo_lr=0.05 / d, engine=name,
@@ -249,41 +249,10 @@ def test_zo_step_engines_agree_on_1x1_mesh():
     assert outs["tree"][1] == outs["fused"][1] == outs["pallas"][1]
 
 
-def test_zo_step_vmap_workers_fallback_close():
-    """The O(1)-in-m vmapped fallback matches the unrolled one (vmap batches
-    the loss evals, so equality is to fp tolerance, not bitwise)."""
-    from repro import compat
-    from repro.core.distributed import make_zo_step
-    from repro.launch.mesh import make_test_mesh
-    from repro.opt.optimizers import const_schedule, sgd
-
-    def loss_fn(p, b):
-        return 0.5 * jnp.mean(jnp.sum((p["x"] - b["t"]) ** 2, -1))
-
-    d = 96
-    params = {"x": jnp.linspace(-1.0, 1.0, d)}
-    batch = {"t": jnp.ones((8, d), jnp.float32)}
-    mesh = make_test_mesh(data=1, model=1)
-    outs = {}
-    with compat.set_mesh(mesh):
-        for vw in (False, True):
-            ho = HOSGDConfig(tau=1 << 30, mu=1e-2, m=4, lr=0.05,
-                             zo_lr=0.05 / d)
-            opt = sgd(const_schedule(ho.lr))
-            zo = jax.jit(make_zo_step(loss_fn, mesh, ho, opt, m=4,
-                                      vmap_workers=vw))
-            p1, _, loss = zo(jnp.int32(3), params, opt.init(params), batch)
-            outs[vw] = (np.asarray(p1["x"]), float(loss))
-    np.testing.assert_allclose(outs[True][0], outs[False][0],
-                               rtol=1e-4, atol=1e-6)
-    assert outs[True][1] == pytest.approx(outs[False][1], rel=1e-5)
-
-
 @pytest.mark.parametrize("engine", ["fused", "pallas"])
 def test_zo_step_memory_o_params_independent_of_m(engine):
     """No materialized full-leaf direction buffer: the compiled ZO step's
     temp memory is O(params) — flat in m (ISSUE 2 acceptance criterion)."""
-    from repro import compat
     from repro.core.distributed import make_zo_step
     from repro.launch.hlo import memory_summary
     from repro.launch.mesh import make_test_mesh
@@ -296,7 +265,7 @@ def test_zo_step_memory_o_params_independent_of_m(engine):
     params = {"x": jnp.zeros((d,))}
     mesh = make_test_mesh(data=1, model=1)
     temps = {}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for m in (2, 8):
             batch = {"t": jnp.ones((m, d), jnp.float32)}
             ho = HOSGDConfig(tau=1 << 30, mu=1e-3, m=m, lr=0.05, zo_lr=1e-6,
@@ -445,9 +414,9 @@ def test_flat_nonsgd_optimizer_falls_back_to_generic_path():
 
 
 def test_zo_step_flat_matches_fused_on_1x1_mesh():
-    """distributed make_zo_step: the flat fused path (zo_auto branch) is
-    loss/params-equivalent (rtol) to the fused engine's generic path."""
-    from repro import compat
+    """distributed make_zo_step: the flat engine's kernels (one launch per
+    primitive inside the shard_map lowering) are loss/params-equivalent
+    (rtol) to the fused engine."""
     from repro.core.distributed import make_zo_step
     from repro.launch.mesh import make_test_mesh
     from repro.opt.optimizers import const_schedule, sgd
@@ -457,7 +426,7 @@ def test_zo_step_flat_matches_fused_on_1x1_mesh():
     batch = {"t": jnp.ones((4, d), jnp.float32)}
     mesh = make_test_mesh(data=1, model=1)
     outs = {}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for name in ("fused", "flat"):
             ho = HOSGDConfig(tau=1 << 30, mu=1e-3, m=2, lr=0.05,
                              zo_lr=0.05 / d, engine=name, momentum=0.9)

@@ -177,8 +177,19 @@ FLAT_LAYOUTS = [
 ]
 
 
+def _flat_layout(sizes, block, base_salt=100):
+    """The kernels' per-leaf tables: (first block, element count, salt)."""
+    starts, b0 = [], 0
+    for n in sizes:
+        starts.append(b0)
+        b0 += max(1, -(-n // block))
+    return (jnp.asarray(starts, jnp.int32), jnp.asarray(sizes, jnp.int32),
+            base_salt + jnp.arange(len(sizes), dtype=jnp.uint32))
+
+
 def _flat_meta(sizes, block, base_salt=100):
-    """Per-block (leaf salt, leaf-local counter start, valid lanes)."""
+    """The oracles' per-block (leaf salt, leaf-local counter start, valid
+    lanes) — built independently of the kernels' leaf lookup."""
     salts, ctrs, nvalid = [], [], []
     for li, n in enumerate(sizes):
         for b in range(max(1, -(-n // block))):
@@ -202,9 +213,10 @@ def _packed(sizes, block, key=KEY):
 @pytest.mark.parametrize("sizes,block", FLAT_LAYOUTS)
 def test_zo_perturb_flat_sweep(sizes, block):
     salts, ctrs, nvalid = _flat_meta(sizes, block)
+    starts, lsizes, lsalts = _flat_layout(sizes, block)
     x = _packed(sizes, block)
     scale = jnp.float32(3e-3)
-    out = ops.zo_perturb_flat(x, salts, ctrs, nvalid, scale, block=block)
+    out = ops.zo_perturb_flat(x, starts, lsizes, lsalts, scale, block=block)
 
     @jax.jit
     def oracle(x, scale):
@@ -225,9 +237,11 @@ def test_zo_reconstruct_flat_sweep(sizes, block, acc_dtype):
     m = 4
     salts1, ctrs, nvalid = _flat_meta(sizes, block)
     msalts = jnp.stack([salts1 + jnp.uint32(w * 1009) for w in range(m)], axis=1)
+    starts, lsizes, lsalts = _flat_layout(sizes, block)
+    wsalts = jnp.stack([lsalts + jnp.uint32(w * 1009) for w in range(m)])
     coeffs = jnp.asarray([0.5, -1.0, 2.0, 0.1], jnp.float32)
-    out = ops.zo_reconstruct_flat(msalts, coeffs, ctrs, nvalid, block=block,
-                                  acc_dtype=acc_dtype)
+    out = ops.zo_reconstruct_flat(int(salts1.shape[0]), starts, lsizes, wsalts,
+                                  coeffs, block=block, acc_dtype=acc_dtype)
 
     @jax.jit
     def oracle(coeffs):
@@ -249,8 +263,9 @@ def test_zo_perturb_sumsq_matches_oracle(sizes, block):
     """One launch = perturb AND the tree-wide sumsq (blockwise-sequential
     accumulation, mirrored exactly by the oracle)."""
     salts, ctrs, nvalid = _flat_meta(sizes, block)
+    starts, lsizes, lsalts = _flat_layout(sizes, block)
     x = _packed(sizes, block)
-    out, ss = ops.zo_perturb_sumsq(x, salts, ctrs, nvalid, 1e-3, block=block)
+    out, ss = ops.zo_perturb_sumsq(x, starts, lsizes, lsalts, 1e-3, block=block)
     oracle = jax.jit(lambda x: ref.ref_zo_perturb_sumsq(
         x, salts, ctrs, nvalid, 1e-3, block=block))
     want, wss = oracle(x)
@@ -270,15 +285,18 @@ def test_zo_reconstruct_update_matches_ref(momentum):
     salts1, ctrs, nvalid = _flat_meta(sizes, block)
     m = 4
     msalts = jnp.stack([salts1 + jnp.uint32(w * 613) for w in range(m)], axis=1)
+    starts, lsizes, lsalts = _flat_layout(sizes, block)
+    wsalts = jnp.stack([lsalts + jnp.uint32(w * 613) for w in range(m)])
     # leaf 0 (4 blocks of 256) fp32; leaf 1 (2 blocks) commits through bf16
     bf16 = jnp.asarray([0, 0, 0, 0, 1, 1], jnp.int32)
+    leaf_bf16 = jnp.asarray([0, 1], jnp.int32)
     coeffs = jnp.asarray([0.25, -0.75, 1.5, 0.3], jnp.float32)
     p = _packed(sizes, block)
     mom = None if momentum == 0.0 else jnp.zeros_like(p) + 0.1
     lr = 0.05
     got_p, got_m = ops.zo_reconstruct_update(
-        p.copy(), None if mom is None else mom.copy(), msalts, ctrs, nvalid,
-        bf16, coeffs, lr, momentum=momentum, block=block)
+        p.copy(), None if mom is None else mom.copy(), starts, lsizes,
+        leaf_bf16, wsalts, coeffs, lr, momentum=momentum, block=block)
     oracle = jax.jit(lambda p, mom, c: ref.ref_zo_reconstruct_update(
         p, mom, msalts, ctrs, nvalid, bf16, c, lr, momentum=momentum,
         block=block))
@@ -304,16 +322,17 @@ def test_zo_reconstruct_update_matches_opt_apply(m, acc_dtype):
     from repro.opt.optimizers import apply_deltas, const_schedule, sgd
 
     sizes, block = [1000, 261], 256
-    salts1, ctrs, nvalid = _flat_meta(sizes, block)
-    msalts = jnp.stack([salts1 + jnp.uint32(w * 271) for w in range(m)], axis=1)
-    bf16 = jnp.zeros((salts1.shape[0],), jnp.int32)
+    starts, lsizes, lsalts = _flat_layout(sizes, block)
+    wsalts = jnp.stack([lsalts + jnp.uint32(w * 271) for w in range(m)])
+    n_blocks = int(starts[-1]) + 2
+    bf16 = jnp.zeros((len(sizes),), jnp.int32)
     lr, momentum = 0.05, 0.9
     opt = sgd(const_schedule(lr), momentum)
 
     @jax.jit
     def step_unfused(p, state, coeffs, t):
-        g = ops.zo_reconstruct_flat(msalts, coeffs, ctrs, nvalid, block=block,
-                                    acc_dtype=acc_dtype)
+        g = ops.zo_reconstruct_flat(n_blocks, starts, lsizes, wsalts, coeffs,
+                                    block=block, acc_dtype=acc_dtype)
         deltas, state = opt.update(g, state, p, t)
         return apply_deltas(p, deltas), state
 
@@ -324,7 +343,7 @@ def test_zo_reconstruct_update_matches_opt_apply(m, acc_dtype):
         coeffs = jnp.linspace(-1.0, 1.0, m + 1)[1:] * jnp.float32(t + 1)
         p_ref, state = step_unfused(p_ref, state, coeffs, t)
         p_k, mom_k = ops.zo_reconstruct_update(
-            p_k, mom_k, msalts, ctrs, nvalid, bf16, coeffs, lr,
+            p_k, mom_k, starts, lsizes, bf16, wsalts, coeffs, lr,
             momentum=momentum, block=block, acc_dtype=acc_dtype)
     np.testing.assert_array_equal(np.asarray(p_k), np.asarray(p_ref))
     np.testing.assert_array_equal(np.asarray(mom_k), np.asarray(state))
@@ -334,12 +353,10 @@ def test_zo_reconstruct_update_donates_eagerly():
     """The commit op consumes its packed buffers in place (donation) — the
     flat engine's fused step path relies on never re-reading them."""
     sizes, block = [129], 64
-    salts1, ctrs, nvalid = _flat_meta(sizes, block)
-    msalts = salts1[:, None]
-    bf16 = jnp.zeros_like(nvalid)
+    starts, lsizes, lsalts = _flat_layout(sizes, block)
     p = _packed(sizes, block)
     out, _ = ops.zo_reconstruct_update(
-        p, None, msalts, ctrs, nvalid, bf16,
+        p, None, starts, lsizes, jnp.zeros((1,), jnp.int32), lsalts[None],
         jnp.ones((1,), jnp.float32), 0.1, block=block)
     assert p.is_deleted()
     assert not out.is_deleted()

@@ -13,7 +13,7 @@ def test_pallas_forward_matches_jnp(arch):
     # kernel-aligned smoke shapes: S multiple of 64, d_inner multiple of 64
     cfg = get_config(arch).reduced().with_(remat=False, ssm_expand=2)
     if cfg.layer_pattern == "local_global":
-        # mixed windows fall back to jnp; force the uniform-window variant
+        # the kernel path refuses mixed windows: force the uniform variant
         cfg = cfg.with_(long_context=True)
     if cfg.has_ssm:
         cfg = cfg.with_(d_model=128)  # d_inner = 256, 64-aligned

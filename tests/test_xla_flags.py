@@ -7,10 +7,12 @@ import os
 import pytest
 
 from repro.launch.xla import (
+    CHECKOUT,
     OVERLAP_FLAGS,
     append_xla_flags,
     compose_xla_flags,
     enable_collective_overlap,
+    use_compile_cache,
 )
 
 USER = "--xla_gpu_enable_latency_hiding_scheduler=true --xla_dump_to=/tmp/d"
@@ -85,3 +87,29 @@ def test_dryrun_composes_instead_of_clobbering(monkeypatch):
     assert "--xla_force_host_platform_device_count=8" not in flags
     for f in USER.split():
         assert f in flags
+
+
+@pytest.fixture
+def cache_dir_config():
+    """Restore jax's compile-cache directory after a test changes it."""
+    import jax
+    saved = jax.config.jax_compilation_cache_dir
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", saved)
+
+
+def test_compile_cache_env_wins(monkeypatch, tmp_path, cache_dir_config):
+    """JAX reads JAX_COMPILATION_CACHE_DIR itself: the helper sets nothing."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = cache_dir_config.jax_compilation_cache_dir
+    assert use_compile_cache() == str(tmp_path)
+    assert cache_dir_config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_ignored_checkout_dir(monkeypatch,
+                                                        cache_dir_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = use_compile_cache()
+    assert path == str(CHECKOUT / ".jax_cache")
+    assert cache_dir_config.jax_compilation_cache_dir == path
+    assert "/.jax_cache/" in (CHECKOUT / ".gitignore").read_text().split()

@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.configs import get_config
 from repro.core.distributed import make_zo_step
 from repro.core.ho_sgd import HOSGDConfig
@@ -25,7 +24,7 @@ def test_bf16_accumulator_close_to_fp32():
     batch = {"tokens": jnp.asarray(toks), "labels": jnp.asarray(labels)}
 
     outs = {}
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for dt in ("float32", "bfloat16"):
             ho = HOSGDConfig(tau=1 << 30, mu=1e-3, m=1, lr=0.05,
                              zo_lr=0.05 / d, acc_dtype=dt)
