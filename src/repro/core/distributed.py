@@ -169,8 +169,9 @@ def lower_fo_round(
             # at the reducer — every worker receives m codes (nbytes * m)
             mb = jax.tree.map(
                 lambda x: x.reshape(m, x.shape[0] // m, *x.shape[1:]), batch)
-            losses, grads_m = jax.vmap(
-                lambda b: jax.value_and_grad(loss_fn)(params, b))(mb)
+            with jax.named_scope("fo.grad"):
+                losses, grads_m = jax.vmap(
+                    lambda b: jax.value_and_grad(loss_fn)(params, b))(mb)
             key_t = jax.random.fold_in(jax.random.key(seed), t)
             dec, wire = [], 0
             for w in range(m):
@@ -186,7 +187,8 @@ def lower_fo_round(
             loss = jnp.mean(losses)
             coll.note_all_reduce(grads, nbytes=wire, tag=compressor.name)
         elif grad_accum <= 1:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            with jax.named_scope("fo.grad"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         else:
             # split so the *major* dim stays the (sharded) batch dim, then
             # transpose: reshape(accum, B/accum, ...) would force GSPMD to
@@ -201,16 +203,19 @@ def lower_fo_round(
 
             def micro(carry, batch_i):
                 g_acc, l_acc = carry
-                l, g = jax.value_and_grad(loss_fn)(params, batch_i)
-                g_acc = jax.tree.map(
-                    lambda a, gg: a + gg.astype(jnp.float32), g_acc, g)
+                with jax.named_scope("fo.grad"):
+                    l, g = jax.value_and_grad(loss_fn)(params, batch_i)
+                with jax.named_scope("fo.accumulate"):
+                    g_acc = jax.tree.map(
+                        lambda a, gg: a + gg.astype(jnp.float32), g_acc, g)
                 return (g_acc, l_acc + l), None
 
             init = (jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                                  params), jnp.float32(0.0))
             (grads, loss), _ = jax.lax.scan(
                 micro, init, mb, unroll=grad_accum if scan_unroll else 1)
-            grads = jax.tree.map(lambda g: g / grad_accum, grads)
+            with jax.named_scope("fo.accumulate"):
+                grads = jax.tree.map(lambda g: g / grad_accum, grads)
             loss = loss / grad_accum
         if not per_worker:
             # the d-dim gradient all-reduce is inserted by GSPMD (sharded
@@ -225,8 +230,9 @@ def lower_fo_round(
                 coll.note_all_reduce(grads, tag="grads")
         if buckets > 1:
             grads = _bucketed_reduce_form(grads, buckets)
-        deltas, opt_state = opt.update(grads, opt_state, params, t)
-        return apply_deltas(params, deltas), opt_state, loss
+        with jax.named_scope("fo.update"):
+            deltas, opt_state = opt.update(grads, opt_state, params, t)
+            return apply_deltas(params, deltas), opt_state, loss
 
     return fo_step
 
@@ -317,8 +323,9 @@ def lower_zo_round(
                            acc_dtype=ho.acc_dtype)
 
     def _scaled(eng, cs, t):
-        rec = eng.reconstruct(cs, t)
-        return jax.tree.map(lambda a: a * (ho.zo_scale / m), rec)
+        with jax.named_scope("zo.reconstruct"):
+            rec = eng.reconstruct(cs, t)
+            return jax.tree.map(lambda a: a * (ho.zo_scale / m), rec)
 
     def zo_inner(t, params, batch_local):
         eng = engine_for(params)
@@ -333,12 +340,14 @@ def lower_zo_round(
         stacked = jax.tree.map(
             lambda x: x.reshape(k, x.shape[0] // k, *x.shape[1:]), batch_local)
         cs, f0s = eng.zo_coeffs(loss_fn, params, stacked, t, workers, ho.mu)
-        cs = coll.all_gather(cs, wa, tag="zo_coeffs")     # (m,) scalars — the
-        cs = cs.reshape(-1)                               # paper's entire comm
+        with jax.named_scope("zo.exchange"):
+            cs = coll.all_gather(cs, wa, tag="zo_coeffs")  # (m,) scalars — the
+            cs = cs.reshape(-1)                            # paper's entire comm
         g_hat = _scaled(eng, cs, t)
         # averaging the monitoring loss is diagnostics, not Algorithm 1's
         # communication — booked as non-payload so measured bytes stay 4*m
-        loss = coll.pmean(jnp.mean(f0s), wa, tag="loss", payload=False)
+        with jax.named_scope("zo.exchange"):
+            loss = coll.pmean(jnp.mean(f0s), wa, tag="loss", payload=False)
         return g_hat, loss
 
     def zo_single(t, params, batch):
@@ -370,8 +379,9 @@ def lower_zo_round(
                 axis_names=manual,
                 check_vma=False,
             )(params, batch)
-        deltas, opt_state = opt.update(g_hat, opt_state, params, t)
-        return apply_deltas(params, deltas), opt_state, loss
+        with jax.named_scope("zo.update"):
+            deltas, opt_state = opt.update(g_hat, opt_state, params, t)
+            return apply_deltas(params, deltas), opt_state, loss
 
     return zo_step
 

@@ -142,7 +142,8 @@ class DirectionEngine:
         )
 
     def inv_norm(self, t, worker) -> jax.Array:
-        return jax.lax.rsqrt(self.sumsq(t, worker) + 1e-30)
+        with jax.named_scope("zo.norm"):
+            return jax.lax.rsqrt(self.sumsq(t, worker) + 1e-30)
 
     # ---- primitive 2: perturb ------------------------------------------- #
     def perturb(self, params: Any, t, worker, scale) -> Any:
@@ -158,9 +159,12 @@ class DirectionEngine:
         """Two function evaluations -> (c, f0) with
         c = (d/mu) * [F(x + mu*v) - F(x)]."""
         inv = self.inv_norm(t, worker)
-        f0 = loss_fn(params, batch)
-        f1 = loss_fn(self.perturb(params, t, worker, jnp.float32(mu) * inv),
-                     batch)
+        with jax.named_scope("zo.forward"):
+            f0 = loss_fn(params, batch)
+        with jax.named_scope("zo.perturb"):
+            x = self.perturb(params, t, worker, jnp.float32(mu) * inv)
+        with jax.named_scope("zo.forward"):
+            f1 = loss_fn(x, batch)
         return ((self.dim / mu) * (f1 - f0)).astype(jnp.float32), f0
 
     def zo_coeffs(self, loss_fn: Callable, params: Any, batches: Any, t,

@@ -89,7 +89,7 @@ def main(argv=None):
         tracer = None
         if args.trace:
             from repro.obs import Tracer
-            tracer = Tracer(clock="sim")
+            tracer = Tracer()
         res = replay(eng, spec, cm, tracer=tracer)
         sync = replay_seed_sync(spec, cm, batch=args.slots)
         fields = ["rid", "arrival", "prompt_len", "max_new", "ttft",

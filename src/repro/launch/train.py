@@ -9,6 +9,8 @@ code drives a TPU slice).  Example — train a ~100M model for 200 steps:
 from __future__ import annotations
 
 import argparse
+import json
+import os
 import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
@@ -30,6 +32,7 @@ from repro.launch.mesh import make_test_mesh
 from repro.launch.xla import use_compile_cache
 from repro.metrics import CSVLogger, comm_report
 from repro.models import transformer as T
+from repro.obs import scopes
 from repro.opt.optimizers import sgd, const_schedule
 
 
@@ -109,10 +112,10 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="append the async-collective + latency-hiding "
                          "scheduler XLA flags (launch.xla, composed with "
                          "any user-set XLA_FLAGS, never replacing them)")
-    ap.add_argument("--trace", default=None, metavar="OUT.json",
-                    help="write a wall-clock Perfetto trace: one span per "
-                         "jitted FO/ZO step (ledger bytes attached) plus a "
-                         "cumulative received-bytes counter")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="run under a jax.profiler trace written to DIR "
+                         "(host spans and device ops on one clock), with "
+                         "the step programs' op scopes in DIR/op_scopes.json")
     return ap.parse_args(argv)
 
 
@@ -147,6 +150,25 @@ class Trainer:
         return comm_report(self.ledger, d=self.d, m=self.m,
                            tau=self.args.tau, codec=self.codec,
                            leaf_dims=self.leaf_dims, grad_bytes=grad_bytes)
+
+    def op_scopes(self) -> Dict[str, Dict[str, str]]:
+        """``{program: {instruction: op_name}}`` of each step program that
+        has run (``fo``, ``zo``), compiled for the current state's shapes
+        and shardings, keyed by the module name a profiler trace gives it
+        (``jit_fo_step``); ``repro.obs.scopes`` classifies the op_names."""
+        a = self.args
+        host = next(token_batches(self.cfg.vocab_size, a.batch, a.seq,
+                                  seed=a.seed))
+        out = {}
+        with jax.set_mesh(self.mesh):
+            batch = next(shard_batches(iter([host]), self.mesh))
+            for name, fn in self.jitted.items():
+                if not self.ledger.steps.get(name):
+                    continue
+                text = fn.lower(jnp.int32(0), self.params, self.opt_state,
+                                batch).compile().as_text()
+                out[scopes.module_name(text)] = scopes.op_names(text)
+        return out
 
 
 def setup(args: argparse.Namespace) -> Trainer:
@@ -203,72 +225,86 @@ def run(tr: Trainer, on_step: Optional[Callable] = None) -> float:
     """The HO-SGD loop; returns the last step's loss.
 
     ``on_step(t, name, loss, dt, params, batch)`` is called after every
-    step with the step's ("fo" or "zo") blocking wall time ``dt`` and the
-    updated device-resident params.
+    step with the step's ("fo" or "zo") wall time ``dt`` from its dispatch
+    to the blocking read of its loss, and the updated device-resident
+    params.
+
+    Host spans, recorded while a profiler session is active: each step is
+    a ``train.step`` (args ``step_num``, ``kind`` and ``wire_bytes``, the
+    ledger's payload bytes of the step) over ``train.data`` (the next
+    sharded batch: the token draw and its ``device_put``),
+    ``train.dispatch`` (the step call, up to its return), ``train.block``
+    (``float(loss)``), ``train.log`` and then ``on_step``; a checkpoint
+    save is ``train.checkpoint``.
     """
     args = tr.args
+    span = jax.profiler.TraceAnnotation
     with jax.set_mesh(tr.mesh):
-        host = token_batches(tr.cfg.vocab_size, args.batch, args.seq,
-                             seed=args.seed)
+        batches = shard_batches(token_batches(
+            tr.cfg.vocab_size, args.batch, args.seq, seed=args.seed), tr.mesh)
         since_fo = 0
-        tracer = None
-        if args.trace:
-            from repro.obs import Tracer
-            tracer = Tracer(clock="wall")
         with CSVLogger(args.log,
                        ["step", "order", "loss", "dt", "comm_bytes"]) as logger:
             t_prev = time.perf_counter()
-            for t, batch in zip(range(args.steps), shard_batches(host, tr.mesh)):
+            for t in range(args.steps):
                 if tr.tau_sched is None:
                     is_fo, t_step = t % args.tau == 0, t
                 else:
                     is_fo, t_step, since_fo = adaptive_tau_decision(
                         t, since_fo, tr.tau_sched(t), args.tau)
                 name = "fo" if is_fo else "zo"
-                step = tr.steps[name]
-                t0 = time.perf_counter()
-                if tracer is not None:
-                    with tracer.span("compute", "train", name=f"{name}/{t}") as sp:
-                        tr.params, tr.opt_state, loss = step(
+                with jax.profiler.StepTraceAnnotation(
+                        "train.step", step_num=t, kind=name) as step_span:
+                    with span("train.data"):
+                        batch = next(batches)
+                    t0 = time.perf_counter()
+                    with span("train.dispatch"):
+                        tr.params, tr.opt_state, loss = tr.steps[name](
                             jnp.int32(t_step), tr.params, tr.opt_state, batch)
+                    with span("train.block"):
                         loss = float(loss)       # blocks: dispatch is async
-                        sp.nbytes = tr.ledger.bytes_per_step(name)
-                    tracer.counter(tracer.now(), "train", "ledger_bytes",
-                                   tr.ledger.total_bytes())
-                else:
-                    tr.params, tr.opt_state, loss = step(
-                        jnp.int32(t_step), tr.params, tr.opt_state, batch)
-                    loss = float(loss)           # blocks: dispatch is async
-                dt_step = time.perf_counter() - t0
-                if t % 10 == 0 or t == args.steps - 1:
-                    now = time.perf_counter()
-                    print(f"step {t:5d} ({'FO' if is_fo else 'ZO'}) "
-                          f"loss={loss:.4f} dt={now - t_prev:.2f}s")
-                    t_prev = now
-                logger.log(step=t, order=int(is_fo), loss=loss, dt=dt_step,
-                           comm_bytes=tr.ledger.bytes_per_step(name))
-                if on_step is not None:
-                    on_step(t, name, loss, dt_step, tr.params, batch)
+                    dt_step = time.perf_counter() - t0
+                    # booked when the program was traced: known only now on
+                    # the first step of each kind
+                    wire = tr.ledger.bytes_per_step(name)
+                    step_span.set_metadata(wire_bytes=wire)
+                    with span("train.log"):
+                        if t % 10 == 0 or t == args.steps - 1:
+                            now = time.perf_counter()
+                            print(f"step {t:5d} ({'FO' if is_fo else 'ZO'}) "
+                                  f"loss={loss:.4f} dt={now - t_prev:.2f}s")
+                            t_prev = now
+                        logger.log(step=t, order=int(is_fo), loss=loss,
+                                   dt=dt_step, comm_bytes=wire)
+                    if on_step is not None:
+                        on_step(t, name, loss, dt_step, tr.params, batch)
             if args.ckpt:
-                if tracer is not None:
-                    with tracer.span("checkpoint", "train", name="ckpt_save"):
-                        path = ckpt_save(args.ckpt, args.steps,
-                                         jax.device_get(tr.params))
-                else:
+                with span("train.checkpoint"):
                     path = ckpt_save(args.ckpt, args.steps,
                                      jax.device_get(tr.params))
                 print("checkpoint:", path)
-        if tracer is not None:
-            from repro.obs import write_trace
-            write_trace(args.trace, tracer, title=f"train:{tr.cfg.name}")
-            print(f"wrote trace {args.trace} ({len(tracer.spans)} spans)")
+    return loss
+
+
+def profile(tr: Trainer, out_dir: str) -> float:
+    """``run`` under a ``jax.profiler`` trace written to ``out_dir``, then
+    the step programs' op scopes (``Trainer.op_scopes``) to
+    ``out_dir/op_scopes.json``; returns the last step's loss."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(out_dir, profiler_options=opts):
+        loss = run(tr)
+    path = os.path.join(out_dir, "op_scopes.json")
+    with open(path, "w") as f:
+        json.dump(tr.op_scopes(), f)
+    print(f"wrote a profile and {path}")
     return loss
 
 
 def main(argv=None):
     use_compile_cache()
     tr = setup(parse_args(argv))
-    loss = run(tr)
+    loss = profile(tr, tr.args.profile) if tr.args.profile else run(tr)
     for line in tr.comm_lines():
         print(line)
     print("done; final loss", loss)

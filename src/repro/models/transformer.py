@@ -120,14 +120,16 @@ def _ffn(cfg: ModelConfig, lp: Params, xn: jax.Array) -> Tuple[jax.Array, jax.Ar
 
 
 def _block(cfg: ModelConfig, lp: Params, x: jax.Array, window: jax.Array):
-    mix = _mix(cfg, lp, apply_norm(cfg, lp["norm1"], x), window)
-    if cfg.post_norms:
-        mix = apply_norm(cfg, lp["post_norm1"], mix)
-    x = x + mix
-    ff, aux = _ffn(cfg, lp, apply_norm(cfg, lp["norm2"], x))
-    if cfg.post_norms:
-        ff = apply_norm(cfg, lp["post_norm2"], ff)
-    return x + ff, aux
+    with jax.named_scope("model.attn"):
+        mix = _mix(cfg, lp, apply_norm(cfg, lp["norm1"], x), window)
+        if cfg.post_norms:
+            mix = apply_norm(cfg, lp["post_norm1"], mix)
+        x = x + mix
+    with jax.named_scope("model.mlp"):
+        ff, aux = _ffn(cfg, lp, apply_norm(cfg, lp["norm2"], x))
+        if cfg.post_norms:
+            ff = apply_norm(cfg, lp["post_norm2"], ff)
+        return x + ff, aux
 
 
 # --------------------------------------------------------------------------- #
@@ -265,16 +267,18 @@ def cross_entropy_streaming(cfg: ModelConfig, head: jax.Array, h: jax.Array,
 
 
 def loss_fn(cfg: ModelConfig, params: Params, batch: Dict) -> jax.Array:
-    h = embed_batch(cfg, params, batch)
+    with jax.named_scope("model.embed"):
+        h = embed_batch(cfg, params, batch)
     h, aux = forward_hidden(cfg, params, h)
-    if ce_chunk_size(cfg):
-        h = apply_norm(cfg, params["final_norm"], h)
-        head = params["embed"].T if "head" not in params else params["head"]
-        ce = cross_entropy_streaming(cfg, head, h, batch["labels"])
-    else:
-        logits = compute_logits(cfg, params, h)
-        ce = cross_entropy(logits, batch["labels"])
-    return ce + MOE_AUX_COEF * aux
+    with jax.named_scope("model.head"):
+        if ce_chunk_size(cfg):
+            h = apply_norm(cfg, params["final_norm"], h)
+            head = params["embed"].T if "head" not in params else params["head"]
+            ce = cross_entropy_streaming(cfg, head, h, batch["labels"])
+        else:
+            logits = compute_logits(cfg, params, h)
+            ce = cross_entropy(logits, batch["labels"])
+        return ce + MOE_AUX_COEF * aux
 
 
 # --------------------------------------------------------------------------- #

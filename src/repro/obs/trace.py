@@ -1,19 +1,15 @@
-"""Span tracing: the one observability vocabulary for train / sim / serve.
+"""Span tracing for the simulator and the serving replay.
 
 A ``Span`` is a half-open interval ``[t0, t1]`` on a *lane* (one lane per
 simulated worker, pod link or serving slot) with a ``kind`` drawn from the
-fixed taxonomy below, an optional byte payload (``nbytes`` — always
-ledger-measured, never re-derived) and an optional parent for nesting.
+fixed taxonomy below and an optional byte payload (``nbytes`` — always
+ledger-measured, never re-derived).
 
-Two clock modes (``Tracer(clock=...)``):
-
-* ``"sim"`` — deterministic simulated time: every span's ``t0``/``t1`` is
-  supplied by the caller (the discrete-event loop, the traffic replay).
-  Nothing here reads a wall clock, so same spec seed ⇒ identical spans ⇒
-  byte-identical Perfetto export (``repro.obs.export``).
-* ``"wall"`` — host wall clock: ``Tracer.span`` is a context manager that
-  stamps ``perf_counter`` deltas against the tracer's epoch and nests via
-  an explicit span stack (the real-path ``launch.train --trace`` mode).
+Every span's ``t0``/``t1`` is supplied by the caller (the discrete-event
+loop, the traffic replay).  Nothing here reads a wall clock, so same spec
+seed ⇒ identical spans ⇒ byte-identical Perfetto export
+(``repro.obs.export``).  The training path's spans are ``jax.profiler``
+annotations instead, on the device trace's clock (``launch.train.run``).
 
 The tracer is bookkeeping-free by design: consumers derive timelines
 (``export``) and attribution (``report``) from the SAME spans — there is
@@ -22,10 +18,8 @@ the ledger recorded.
 """
 from __future__ import annotations
 
-import time
-from contextlib import contextmanager
-from dataclasses import dataclass, field
-from typing import Iterator, List, Optional, Tuple
+from dataclasses import dataclass
+from typing import List, Optional, Tuple
 
 #: the span taxonomy — every span's ``kind`` is one of these
 KINDS = (
@@ -38,8 +32,6 @@ KINDS = (
     "prefill",          # serving: admission prefill on a slot
     "decode",           # serving: decode occupancy of a slot
 )
-
-CLOCKS = ("sim", "wall")
 
 
 def worker_lane(worker: int) -> str:
@@ -68,7 +60,6 @@ class Span:
     nbytes: int = 0
     worker: int = -1
     src_kind: Optional[str] = None
-    parent: int = -1
 
     def __post_init__(self):
         assert self.kind in KINDS, \
@@ -86,52 +77,21 @@ CounterSample = Tuple[float, str, str, float]
 
 
 class Tracer:
-    """Collects spans and counter samples under one clock mode."""
+    """Collects spans and counter samples at caller-supplied times."""
 
-    def __init__(self, clock: str = "sim"):
-        assert clock in CLOCKS, f"unknown clock {clock!r}; have {CLOCKS}"
-        self.clock = clock
+    def __init__(self):
         self.spans: List[Span] = []
         self.counters: List[CounterSample] = []
-        self._stack: List[int] = []
-        self._epoch = time.perf_counter() if clock == "wall" else 0.0
 
     # ------------------------------------------------------------------ #
-    def now(self) -> float:
-        """Wall-clock seconds since the tracer's epoch (wall mode only)."""
-        assert self.clock == "wall", "sim-mode time is supplied by callers"
-        return time.perf_counter() - self._epoch
-
     def add(self, kind: str, lane: str, t0: float, t1: float, *,
             name: str = "", nbytes: int = 0, worker: int = -1,
-            src_kind: Optional[str] = None,
-            parent: Optional[int] = None) -> int:
-        """Record a completed span (sim mode's only entry point); returns
-        its index.  ``parent=None`` nests under the innermost open wall
-        span, if any."""
-        if parent is None:
-            parent = self._stack[-1] if self._stack else -1
+            src_kind: Optional[str] = None) -> int:
+        """Record a completed span; returns its index."""
         self.spans.append(Span(kind, lane, float(t0), float(t1), name=name,
                                nbytes=int(nbytes), worker=worker,
-                               src_kind=src_kind, parent=parent))
+                               src_kind=src_kind))
         return len(self.spans) - 1
-
-    @contextmanager
-    def span(self, kind: str, lane: str, *, name: str = "",
-             nbytes: int = 0) -> Iterator[Span]:
-        """Wall-clock span context manager: stamps ``now()`` on entry and
-        exit, nests under the enclosing ``span``.  The yielded ``Span`` is
-        live — mutate ``nbytes``/``name`` inside the block (e.g. once the
-        CommLedger has booked the step)."""
-        assert self.clock == "wall", "use add() with explicit times in sim mode"
-        idx = self.add(kind, lane, self.now(), self.now(), name=name,
-                       nbytes=nbytes)
-        self._stack.append(idx)
-        try:
-            yield self.spans[idx]
-        finally:
-            self._stack.pop()
-            self.spans[idx].t1 = self.now()
 
     def counter(self, t: float, lane: str, name: str, value: float) -> None:
         self.counters.append((float(t), lane, name, float(value)))

@@ -169,7 +169,7 @@ def replay(engine, spec: TrafficSpec, compute: ComputeModel,
     ``overheads`` (dispatch per launch, sampling per decode step).  Returns
     the event trace, the per-request latency table and summary statistics.
 
-    ``tracer`` (a sim-clock ``repro.obs.Tracer``) additionally records each
+    ``tracer`` (a ``repro.obs.Tracer``) additionally records each
     request's lifecycle on its admission slot's lane — ``queue.contention``
     (arrival → admission), ``prefill`` (admission → first token), ``decode``
     (first token → retire) — plus a live-slot counter per decode step; the
@@ -181,8 +181,6 @@ def replay(engine, spec: TrafficSpec, compute: ComputeModel,
     assert engine.sc.max_seq >= spec.required_max_seq(), \
         "engine max_seq too small for the traffic mix"
     assert not engine.has_work, "replay needs a fresh engine"
-    if tracer is not None:
-        assert tracer.clock == "sim", "traffic replay stamps simulated time"
     t_wall = _time.perf_counter()
     arrivals = poisson_trace(spec)
     n = len(arrivals)
