@@ -88,10 +88,11 @@ class ModelConfig:
     # With fsdp=True a "worker" (the paper's m) is a full data x model slice,
     # so the ZO step's worker axis becomes the pod axis (see DESIGN.md §3).
     fsdp: bool = False
-    # dispatch sequence mixing to the Pallas TPU kernels (flash attention /
-    # selective scan).  Requires static windows (uniform or full) and
-    # kernel-aligned shapes; used on real TPU runtimes and in interpret-mode
-    # equivalence tests — the CPU dry-run lowers the jnp path.
+    # dispatch the mamba selective scan to its Pallas TPU kernel (needs
+    # 64-aligned shapes); used on real TPU runtimes and in interpret-mode
+    # equivalence tests — the CPU dry-run lowers the jnp path.  Attention
+    # needs no flag: models/attention._use_flash picks its kernel from the
+    # target and the shapes.
     use_pallas: bool = False
     source: str = ""                      # citation
 

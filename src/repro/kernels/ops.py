@@ -46,18 +46,14 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
 ) -> jax.Array:
-    """GQA flash attention; returns (B, Sq, H, hd)."""
-    B, Sq, H, hd = q.shape
-    KV = k.shape[2]
-    rep = H // KV
-    qh = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, hd)
-    kh = jnp.repeat(k.transpose(0, 2, 1, 3), rep, axis=1).reshape(B * H, -1, hd)
-    vh = jnp.repeat(v.transpose(0, 2, 1, 3), rep, axis=1).reshape(B * H, -1, hd)
+    """Differentiable GQA flash attention; returns (B, Sq, H, hd)."""
+    heads_major = lambda x: x.transpose(0, 2, 1, 3)
     out = flash_attention_pallas(
-        qh, kh, vh, causal=causal, window=window, softcap=softcap,
-        block_q=block_q, block_k=block_k, interpret=INTERPRET,
+        heads_major(q), heads_major(k), heads_major(v), causal=causal,
+        window=window, softcap=softcap, block_q=block_q, block_k=block_k,
+        interpret=INTERPRET,
     )
-    return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
+    return heads_major(out)
 
 
 @partial(jax.jit, static_argnames=("block_d", "block_s"))

@@ -1,12 +1,15 @@
 """GQA attention: RoPE, qk-norm, logit soft-capping, sliding window, KV cache."""
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.dist.sharding import WORKER_AXIS_ORDER
 from repro.models.layers import apply_rope, dense_init, rmsnorm, softcap
 
 Params = Dict[str, jax.Array]
@@ -76,61 +79,118 @@ def _attend(
     return out.reshape(B, Sq, H * hd).astype(q.dtype)
 
 
-def _attend_seq(cfg: ModelConfig, q, k, v, positions, window) -> jax.Array:
-    """Full-sequence attention, q-chunked when configured.
+def _kernel_platform() -> str:
+    """Device kind the traced program is lowered for: the ambient mesh's
+    devices (a mesh of described TPUs lowers for a TPU on a CPU host), else
+    the default backend."""
+    dev = jax.sharding.get_abstract_mesh().abstract_device
+    return dev.device_kind if dev is not None else jax.default_backend()
 
-    Dense masked attention holds (B, H, Sq, Sk) fp32 scores; streaming query
-    blocks of ``cfg.attn_chunk`` bounds that to (B, H, chunk, Sk) — the
-    XLA-level analogue of the Pallas flash kernel's VMEM tiling (which is the
-    real-TPU path; see kernels/flash_attention.py).
+
+def _use_flash(cfg: ModelConfig, S: int, hd: int, platform: str) -> bool:
+    """Whether full-sequence attention takes the blocked (flash) kernel.
+
+    Taken on a TPU (anywhere else Pallas would only interpret) when the
+    sequence is at least two 128-row blocks, every layer shares one static
+    window, and the kernel takes the head dim; otherwise the chunked dense
+    path runs."""
+    return (platform.lower().startswith("tpu")
+            and S % 128 == 0 and S >= 256
+            and len(set(cfg.layer_windows())) == 1
+            and hd % 16 == 0 and hd <= 256)
+
+
+def _flash_block(S: int) -> int:
+    # 1024 x 1024 blocks: 14.1 ms a layer's forward at phi3 widths, batch
+    # 8, against 20.8 ms at 512 (TPU v5 lite); larger ones overflow VMEM
+    return next(b for b in (1024, 512, 256, 128) if S % b == 0)
+
+
+def _attend_seq(cfg: ModelConfig, q, k, v, positions, window) -> jax.Array:
+    """Full-sequence attention: the blocked kernel where ``_use_flash``
+    allows, else dense masked attention, q-chunked when configured.
+
+    Dense attention holds (B, H, Sq, Sk) fp32 scores; streaming query blocks
+    of ``cfg.attn_chunk`` bounds that to (B, H, chunk, Sk), but each chunk's
+    scores still go through HBM.
     """
     B, S = q.shape[0], q.shape[1]
     causal = not cfg.encoder_only
-    if cfg.use_pallas:
-        # kernel path: needs one static window across layers (or all-full)
-        ws = set(cfg.layer_windows())
-        if len(ws) != 1:
-            raise ValueError(
-                f"use_pallas needs one static window across layers; "
-                f"{cfg.name} has {sorted(ws, key=str)}")
-        return _flash_kernel_call(cfg, q, k, v, causal, next(iter(ws)))
-    chunk = cfg.attn_chunk
-    if chunk:
-        while S % chunk:
-            chunk //= 2
-    if not chunk or S <= chunk:
-        return _attend(cfg, q, k, v, positions, positions, window, causal)
-    nc = S // chunk
+    if _use_flash(cfg, S, q.shape[-1], _kernel_platform()):
+        return _attend_blocked(cfg, q, k, v, causal, cfg.layer_windows()[0])
+    with jax.named_scope("attn.dense"):
+        chunk = cfg.attn_chunk
+        if chunk:
+            while S % chunk:
+                chunk //= 2
+        if not chunk or S <= chunk:
+            return _attend(cfg, q, k, v, positions, positions, window, causal)
+        nc = S // chunk
 
-    def body(_, xs):
-        q_i, pos_i = xs                      # (B, chunk, H, hd), (chunk,)
-        o = _attend(cfg, q_i, k, v, pos_i, positions, window, causal)
-        return None, o
+        def body(_, xs):
+            q_i, pos_i = xs                      # (B, chunk, H, hd), (chunk,)
+            o = _attend(cfg, q_i, k, v, pos_i, positions, window, causal)
+            return None, o
 
-    if cfg.remat:
-        body = jax.checkpoint(body)
-    q_c = q.reshape(B, nc, chunk, *q.shape[2:]).swapaxes(0, 1)
-    pos_c = positions.reshape(nc, chunk)
-    _, outs = jax.lax.scan(
-        body, None, (q_c, pos_c), unroll=nc if cfg.scan_unroll else 1)
-    return outs.swapaxes(0, 1).reshape(B, S, -1)
+        if cfg.remat:
+            body = jax.checkpoint(body)
+        q_c = q.reshape(B, nc, chunk, *q.shape[2:]).swapaxes(0, 1)
+        pos_c = positions.reshape(nc, chunk)
+        _, outs = jax.lax.scan(
+            body, None, (q_c, pos_c), unroll=nc if cfg.scan_unroll else 1)
+        return outs.swapaxes(0, 1).reshape(B, S, -1)
 
 
-def _flash_kernel_call(cfg: ModelConfig, q, k, v, causal, w_static):
-    """Dispatch to the Pallas flash-attention kernel (blocks of 128 or 64
-    positions, so the sequence must be a multiple of 64)."""
-    S = q.shape[1]
-    if S % 128 and S % 64:
-        raise ValueError(
-            f"use_pallas needs a sequence length that is a multiple of 64; "
-            f"got {S}")
-    from repro.kernels import ops
-    block = 128 if S % 128 == 0 else 64
-    out = ops.flash_attention(
-        q, k, v, causal=causal, window=w_static, softcap=cfg.attn_softcap,
-        block_q=block, block_k=block)
-    B = q.shape[0]
-    return out.reshape(B, S, -1)
+def _attend_blocked(cfg: ModelConfig, q, k, v, causal: bool,
+                    window: Optional[int], *, interpret: bool = False):
+    """Blocked attention through the Pallas flash kernels (differentiable);
+    q (B, S, H, hd), k/v (B, S, KV, hd) -> (B, S, H * hd).
+
+    Under an ambient mesh the kernel runs in a ``shard_map`` over the mesh
+    axes the trace has not made manual yet (all of them in the GSPMD FO
+    step, the non-worker ones inside the ZO step's), so each device attends
+    over its own sequences and heads and nothing is gathered.
+    """
+    from repro.kernels.flash_attention import flash_attention_pallas
+    block = _flash_block(q.shape[1])
+
+    def attend(q, k, v):
+        heads_major = lambda x: x.transpose(0, 2, 1, 3)
+        o = flash_attention_pallas(
+            heads_major(q), heads_major(k), heads_major(v), causal=causal,
+            window=window, softcap=cfg.attn_softcap, block_q=block,
+            block_k=block, interpret=interpret)
+        return heads_major(o).reshape(q.shape[0], q.shape[1], -1)
+
+    with jax.named_scope("attn.flash"):
+        spec = _flash_shard_spec(q.shape[0], q.shape[2], k.shape[2])
+        if spec is None:
+            return attend(q, k, v)
+        # a nested shard_map names the axes already manual too
+        axes = frozenset(jax.sharding.get_abstract_mesh().axis_names)
+        return jax.shard_map(
+            attend, in_specs=spec, out_specs=P(*spec[:3]), axis_names=axes,
+            check_vma=False)(q, k, v)
+
+
+def _flash_shard_spec(B: int, H: int, KV: int) -> Optional[P]:
+    """The (B, S, H, hd) spec of the kernel's shard_map: the batch over the
+    worker axes and the heads over ``model``, where the trace left them to
+    the partitioner and they divide; None when no ambient mesh axis is left
+    to it (a Mosaic kernel cannot be partitioned, not even over an axis of
+    size 1)."""
+    am = jax.sharding.get_abstract_mesh()
+    free = frozenset(am.axis_names) - frozenset(am.manual_axes)
+    if not free:
+        return None
+    batch = tuple(a for a in WORKER_AXIS_ORDER if a in free)
+    if B % math.prod(am.shape[a] for a in batch):
+        batch = ()
+    heads = None
+    if "model" in free and H % am.shape["model"] == 0 \
+            and KV % am.shape["model"] == 0:
+        heads = "model"
+    return P(batch or None, None, heads, None)
 
 
 def attention_forward(
@@ -165,7 +225,6 @@ def _hd_model_spec(ndim: int):
     aligned, or the partitioner all-gathers the WHOLE cache over the model
     axis per layer (measured: 1 GiB fp32/layer for gemma2 decode_32k —
     EXPERIMENTS.md §Perf iteration 3)."""
-    from jax.sharding import PartitionSpec as P
     try:
         am = jax.sharding.get_abstract_mesh()
     except Exception:

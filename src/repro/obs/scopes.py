@@ -13,7 +13,10 @@ text (``jitted.lower(...).compile().as_text()``), and ``classify`` puts an
 
 Under ``fo.grad`` the phase is told by name alone: backward ops carry
 ``transpose(``, and the recompute of a rematerialized layer carries
-``rematted_computation`` inside the transpose.
+``rematted_computation`` inside the transpose.  Inside ``model.attn`` the
+attention itself runs under ``attn.flash`` (the blocked kernels) or
+``attn.dense`` (the dense fallback), and the layer is named with its path
+(``model.attn/attn.flash``).
 """
 from __future__ import annotations
 
@@ -27,6 +30,8 @@ STEP = ("fo.grad", "fo.accumulate", "fo.update",
 #: layers of the model, inside ``fo.grad`` and ``zo.forward``
 MODEL = ("model.embed", "model.attn", "model.mlp", "model.head")
 SCOPES = STEP + MODEL
+#: the paths of attention inside ``model.attn``
+ATTN_PATHS = ("attn.flash", "attn.dense")
 
 _SCOPE = re.compile(r"(?<![\w.])(%s)(?![\w.])"
                     % "|".join(re.escape(s) for s in SCOPES))
@@ -34,6 +39,8 @@ _SCOPE = re.compile(r"(?<![\w.])(%s)(?![\w.])"
 # metadata={op_name="..." ...}" (ROOT-prefixed in a computation's last line)
 _INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*metadata=\{op_name="([^"]*)"',
                     re.M)
+_ATTN_PATH = re.compile(r"(?<![\w.])(%s)(?![\w.])"
+                        % "|".join(re.escape(s) for s in ATTN_PATHS))
 _MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
 
 
@@ -60,10 +67,15 @@ def scopes_of(op_name: str) -> List[str]:
 def classify(op_name: str) -> Tuple[Optional[str], Optional[str]]:
     """``(part, layer)``: the innermost step scope, ``fo.grad`` split into
     ``fo.grad.forward``, ``fo.grad.backward`` and ``fo.grad.recompute``;
-    and the innermost model scope.  Either is None where none is named."""
+    and the innermost model scope, ``model.attn`` with its attention path
+    where one is named.  Either is None where none is named."""
     found = scopes_of(op_name)
     part = next((s for s in reversed(found) if s in STEP), None)
     layer = next((s for s in reversed(found) if s in MODEL), None)
+    if layer == "model.attn":
+        path = _ATTN_PATH.findall(op_name)
+        if path:
+            layer += "/" + path[-1]
     if part == "fo.grad":
         if "rematted_computation" in op_name:
             part += ".recompute"

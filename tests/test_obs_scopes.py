@@ -30,6 +30,14 @@ SMOKE = ["--arch", "phi3-mini-3.8b", "--reduce", "smoke", "--batch", "4",
                                                          "model.head")),
     ("jit(zo_step)/fo.gradient/model.attn2/add", (None, None)),
     ("jit(fo_step)/model.attn/sin", (None, "model.attn")),
+    ("jit(zo_step)/zo.forward/while/body/closed_call/checkpoint/model.attn/"
+     "attn.flash/pallas_call", ("zo.forward", "model.attn/attn.flash")),
+    ("jit(fo_step)/fo.grad/transpose(jvp())/while/body/closed_call/"
+     "checkpoint/rematted_computation/model.attn/attn.dense/while/body/"
+     "exp", ("fo.grad.recompute", "model.attn/attn.dense")),
+    ("jit(fo_step)/fo.grad/jvp()/model.mlp/attn.flash/add",
+     ("fo.grad.forward", "model.mlp")),
+    ("jit(fo_step)/model.attn/attn.flashy/add", (None, "model.attn")),
 ])
 def test_classify(op_name, want):
     assert S.classify(op_name) == want

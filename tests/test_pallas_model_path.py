@@ -1,4 +1,8 @@
-"""The use_pallas model path (interpret mode) equals the jnp path."""
+"""The use_pallas model path (interpret mode) equals the jnp path.
+
+Attention picks its kernel from the target, not from ``use_pallas``: on the
+CPU both sides run dense attention, and ``tests/test_attention.py`` checks
+the blocked path against it."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,7 +17,7 @@ def test_pallas_forward_matches_jnp(arch):
     # kernel-aligned smoke shapes: S multiple of 64, d_inner multiple of 64
     cfg = get_config(arch).reduced().with_(remat=False, ssm_expand=2)
     if cfg.layer_pattern == "local_global":
-        # the kernel path refuses mixed windows: force the uniform variant
+        # the uniform-window variant, the one the attention kernel takes
         cfg = cfg.with_(long_context=True)
     if cfg.has_ssm:
         cfg = cfg.with_(d_model=128)  # d_inner = 256, 64-aligned

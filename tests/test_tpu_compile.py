@@ -124,12 +124,50 @@ def test_rmsnorm_compiles_for_v5e(one_chip):
         lambda x, s: rmsnorm_pallas(x, s, interpret=False), x, scale)
 
 
+def _phi3_attention(sd):
+    """phi3-mini's attention: 32 heads of 96 over a 4096-token sequence,
+    one sequence (an FO microbatch), the model path's blocks."""
+    q = jax.ShapeDtypeStruct((1, 32, 4096, 96), jnp.bfloat16, sharding=sd)
+    fn = lambda q, k, v: flash_attention_pallas(
+        q, k, v, block_q=1024, block_k=1024, interpret=False)
+    return fn, q
+
+
 def test_flash_attention_compiles_for_v5e(one_chip):
-    # phi3-mini: 32 heads of 96 over a 4096-token sequence
-    q = jax.ShapeDtypeStruct((32, 4096, 96), jnp.bfloat16, sharding=one_chip)
-    _compiles_to_mosaic(
-        lambda q, k, v: flash_attention_pallas(q, k, v, interpret=False),
-        q, q, q)
+    fn, q = _phi3_attention(one_chip)
+    _compiles_to_mosaic(fn, q, q, q)
+
+
+def test_flash_attention_backward_compiles_for_v5e(one_chip):
+    """The forward that saves the log-sum-exp, and the dq and dk/dv
+    kernels: three Mosaic calls."""
+    fn, q = _phi3_attention(one_chip)
+    grads = jax.grad(lambda q, k, v: fn(q, k, v).astype(jnp.float32).sum(),
+                     argnums=(0, 1, 2))
+    text = jax.jit(grads).lower(q, q, q).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_model_attention_takes_the_kernel_on_v5e(topo, one_chip):
+    """Under a mesh of described v5e devices the model's full-sequence
+    attention selects the kernels, in a shard_map (its FO gradient holds
+    the three Mosaic calls and no collective)."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+    from repro.models import attention as A
+    cfg = get_config("phi3-mini-3.8b")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 96), jnp.bfloat16,
+                             sharding=one_chip)
+    pos = jnp.arange(4096, dtype=jnp.int32)
+    loss = lambda q, k, v: A._attend_seq(
+        cfg, q, k, v, pos, jnp.int32(T.FULL_WINDOW)).astype(jnp.float32).sum()
+    with jax.set_mesh(mesh):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, q, q).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "all-gather" not in text and "all-reduce" not in text
 
 
 def test_selective_scan_compiles_for_v5e(one_chip):
