@@ -6,7 +6,8 @@ Everything a cell needs is found by name:
   mix and the chips;
 * ``configs/<config>.json`` holds the model as it is run (``model``), how the
   program is told to build it (``program``) and which plain reference
-  follows it (``reference``: a module under ``reference/``);
+  follows it (``reference``: a module under ``reference/``, which may also
+  give the configuration's FLOP counts, ``flop_counts``);
 * ``traffic/<traffic>.json`` holds the job: rows per chip, sequence length,
   the token distribution, the HO-SGD period and step sizes;
 * ``limits/<workload>.json`` holds the limits of the comparison that decides
@@ -25,6 +26,7 @@ every program there.
 """
 from __future__ import annotations
 
+import dataclasses
 import gc
 import importlib.util
 import json
@@ -34,6 +36,8 @@ import time
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
 import numpy as np
+
+import step_flops as sf
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -169,19 +173,19 @@ class Compiles:
 # --------------------------------------------------------------------------- #
 # the program under test
 # --------------------------------------------------------------------------- #
-CFG_FIELDS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-              "d_ff", "vocab_size", "activation", "norm", "norm_eps",
-              "rope_theta", "dtype", "grad_accum", "tie_embeddings")
-
-
 def check_program_config(tr, cfg: dict):
-    """The program builds the model the configuration file states."""
+    """The program builds the model the configuration file states: every
+    key of the file's ``model`` that names a field of the program's
+    ``ModelConfig`` (``embed_scale`` names none), and ``window``, one value
+    for every layer or a list with one a layer, against the program's
+    ``layer_windows()``."""
     model = cfg["model"]
-    bad = {k: (getattr(tr.cfg, k), model[k]) for k in CFG_FIELDS
-           if getattr(tr.cfg, k) != model[k]}
-    windows = set(tr.cfg.layer_windows())
-    if windows != {model.get("window")}:
-        bad["window"] = (sorted(windows, key=str), model.get("window"))
+    fields = {f.name for f in dataclasses.fields(tr.cfg)} - {"window"}
+    bad = {k: (getattr(tr.cfg, k), v) for k, v in model.items()
+           if k in fields and getattr(tr.cfg, k) != v}
+    windows = list(tr.cfg.layer_windows())
+    if windows != sf.layer_windows(model):
+        bad["window"] = (windows, model.get("window"))
     if bad:
         raise SystemExit(f"program config differs from {cfg['name']}: "
                          f"(program, file) {bad}")
@@ -277,10 +281,14 @@ def window_steps(tau: int, seconds: float, est: Dict[str, float]) -> int:
     return tau * max(2, math.ceil(seconds / max(period, 1e-9)))
 
 
-def timed_window(train, tr, n_steps: int, annotate: bool = False) -> dict:
-    """``n_steps`` steps through ``run``, timed on the host clock; with
-    ``annotate`` the window and each step callback are host spans in the
-    profiler's trace."""
+def timed_window(train, tr, tau: int, seconds: float, est: Dict[str, float],
+                 annotate: bool = False) -> dict:
+    """Whole periods through ``run``, timed on the host clock: the fewest
+    that ``est`` says reach ``seconds`` (``window_steps``), then, where their
+    time fell short, as many more as their measured period says reach it.
+    ``est`` reads the FO step from set-up's first one, which also traces and
+    loads its program, so it can overstate a period.  With ``annotate`` the
+    window and each step callback are host spans in the profiler's trace."""
     import contextlib
     import jax
     span = (jax.profiler.TraceAnnotation if annotate
@@ -293,11 +301,17 @@ def timed_window(train, tr, n_steps: int, annotate: bool = False) -> dict:
             rec["kinds"].append(name)
             rec["losses"].append(loss)
 
-    tr.args.steps = n_steps
+    n = window_steps(tau, seconds, est)
     c0 = Compiles.n
     t0 = time.perf_counter()
     with span("bench.window"):
+        tr.args.steps = n
         train.run(tr, on_step)
+        elapsed = time.perf_counter() - t0
+        if elapsed < seconds:
+            period = elapsed * tau / n
+            tr.args.steps = tau * math.ceil((seconds - elapsed) / period)
+            train.run(tr, on_step)
     rec["wall_s"] = time.perf_counter() - t0
     rec["compiles"] = Compiles.n - c0
     return rec
@@ -436,6 +450,53 @@ def free_program(tr):
 # --------------------------------------------------------------------------- #
 # one run of one cell
 # --------------------------------------------------------------------------- #
+def flop_counts(cfg: dict) -> tuple:
+    """``(forward_flops_per_token, attention_flops)`` of the configuration:
+    its reference module's where it defines them, else ``step_flops``'
+    counts of a dense decoder."""
+    ref = reference_module(cfg)
+    return (getattr(ref, "forward_flops_per_token",
+                    sf.forward_flops_per_token),
+            getattr(ref, "attention_flops", sf.attention_flops))
+
+
+def read_trace(trace_dir: str, chips: int, scopes: dict, log=print) -> tuple:
+    """The traced window's device timings (``xplane_reduce``) and its
+    program spans and device scopes (``span_reduce``, with the step
+    programs' op scopes); the trace directory is removed."""
+    import shutil
+    import span_reduce
+    import xplane_reduce
+    try:
+        red = xplane_reduce.reduce_dir(trace_dir, chips)
+        spans = span_reduce.reduce_dir(trace_dir, scopes)
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    log(f"trace: window {red['window_s']:.3f} s, busy per device "
+        f"{red['busy_s_per_device']}, collectives {red['collective_s']:.4f}"
+        f" s ({red['collective_exposed_s']:.4f} s exposed), programs "
+        f"{ {k: len(v) for k, v in red['modules'].items()} }")
+    log(f"spans: idle {spans['idle']}; scoped share "
+        f"{ {k: p['scoped_share'] for k, p in spans['programs'].items()} }")
+    return red, spans
+
+
+def trace_record(cfg: dict, tf: dict, chips: int, win: dict, red: dict,
+                 spans: dict, device_kind: str) -> dict:
+    """What the per-layer readers read: the window, its trace and spans,
+    the model FLOPs of a step of each kind (all chips), attention's FLOPs
+    in one forward over one chip's rows, and the chip's peak."""
+    fwd, attn = flop_counts(cfg)
+    model, seq = cfg["model"], tf["seq"]
+    step_tokens = global_batch(tf, chips) * seq
+    return {"chips": chips, "tau": tf["tau"], "window": win,
+            "n_steps": len(win["dts"]), "trace": red, "spans": spans,
+            "step_flops": {k: sf.step_flops(model, seq, step_tokens, k, fwd)
+                           for k in sf.PASSES},
+            "attention_flops": attn(model, seq) * step_tokens / chips,
+            "peak_flops": sf.peaks(device_kind)["bf16_flops_per_s"]}
+
+
 def per_layer_metrics(wl_name: str, rec: dict) -> Dict[str, dict]:
     """Every per-layer metric of ``BENCHMARK.json`` that this cell lists (or
     that lists no cells), as its reader finds it."""
@@ -463,10 +524,9 @@ def run_cell(wl: dict, cfg: dict, tf: dict, lim: dict, seed: int,
     n_follow = tf["follow_steps"]
     warm = warm_up(train, tr, n_follow)
     est = step_estimates(warm)
-    n_steps = window_steps(tf["tau"], seconds, est)
     log(f"warm-up losses {warm['losses']} step seconds {warm['dts']} "
-        f"compile seconds {warm['compile_s']}; window: {n_steps} steps "
-        f"(estimated {est}); set-up: {Compiles.backend} compilations, "
+        f"compile seconds {warm['compile_s']}; step seconds estimated "
+        f"{est}; set-up: {Compiles.backend} compilations, "
         f"{Compiles.n - Compiles.backend} cache loads")
     if trace:
         import tempfile
@@ -475,10 +535,13 @@ def run_cell(wl: dict, cfg: dict, tf: dict, lim: dict, seed: int,
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
     setup_s = time.perf_counter() - t_start
-    win = timed_window(train, tr, n_steps, annotate=trace)
+    win = timed_window(train, tr, tf["tau"], seconds, est, annotate=trace)
     if trace:
         jax.profiler.stop_trace()
+    n_steps = len(win["dts"])
     mem = memory_peak_bytes(devices)
+    if trace:
+        scopes = tr.op_scopes()
     tokens = n_steps * global_batch(tf, chips) * tf["seq"]
     log(f"window: {n_steps} steps in {win['wall_s']:.3f} s, step seconds "
         f"{[round(x, 4) for x in win['dts']]}, compiles {win['compiles']}")
@@ -497,22 +560,9 @@ def run_cell(wl: dict, cfg: dict, tf: dict, lim: dict, seed: int,
               "failed": int(sum(1 for x in win["losses"]
                                 if not math.isfinite(x)))}
     if trace:
-        import xplane_reduce
-        red = xplane_reduce.reduce_dir(trace_dir, chips)
-        log(f"trace: window {red['window_s']:.3f} s, busy per device "
-            f"{red['busy_s_per_device']}, collectives {red['collective_s']:.4f}"
-            f" s ({red['collective_exposed_s']:.4f} s exposed), programs "
-            f"{ {k: len(v) for k, v in red['modules'].items()} }")
-        import shutil
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        import step_flops as sf
-        rec = {"chips": chips, "tau": tf["tau"], "window": win,
-               "n_steps": n_steps, "trace": red,
-               "step_flops": {k: sf.step_flops(cfg["model"], tf["seq"],
-                                               tokens // n_steps, k)
-                              for k in ("fo", "zo")},
-               "peak_flops": sf.peaks(devices[0].device_kind)[
-                   "bf16_flops_per_s"]}
+        red, spans = read_trace(trace_dir, chips, scopes, log)
+        rec = trace_record(cfg, tf, chips, win, red, spans,
+                           devices[0].device_kind)
         result["metrics"] = per_layer_metrics(wl["name"], rec)
         result["breakdown"] = red["breakdown"]
         busy, window_s = red["busy_s"], red["window_s"]

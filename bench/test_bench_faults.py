@@ -29,7 +29,8 @@ SEED = 2**31 + 99
 def tiny(dtype=None, tau=4):
     from repro.configs import get_config
     c = get_config("phi3-mini-3.8b").reduced()
-    model = {k: getattr(c, k) for k in H.CFG_FIELDS}
+    model = {k: getattr(c, k) for k in H.config("phi3-mini-3.8b-8l")["model"]
+             if k not in ("window", "embed_scale")}
     model.update(window=None, embed_scale=True)
     if dtype:
         model["dtype"] = dtype

@@ -43,3 +43,54 @@ def test_peaks_by_device_kind():
         F.peaks("TPU v4")
     with pytest.raises(KeyError):
         F.peaks("cpu")
+
+
+def dense_count_before_the_split(model, seq):
+    """The dense count as it stood with attention inline: one window for
+    every layer."""
+    D, H, KV, hd, Fd = (model["d_model"], model["n_heads"],
+                        model["n_kv_heads"], model["head_dim"], model["d_ff"])
+    mlp_mats = 3 if model["activation"] == "swiglu" else 2
+    per_layer_params = D * H * hd * 2 + D * KV * hd * 2 + mlp_mats * D * Fd
+    window = model.get("window") or seq
+    keys = sum(min(i + 1, window) for i in range(seq)) / seq
+    attn = 2 * 2 * H * hd * keys
+    return model["n_layers"] * (2 * per_layer_params + attn) \
+        + 2 * D * model["vocab_size"]
+
+
+def test_attention_split_out_keeps_the_count():
+    """``attention_flops`` plus the rest is the count before the split, to
+    the FLOP: phi3 as run, and at a small size with per-layer windows,
+    where it is the mean of the one-window counts."""
+    phi3 = H.config("phi3-mini-3.8b-8l")["model"]
+    assert F.forward_flops_per_token(phi3, 4096) == \
+        dense_count_before_the_split(phi3, 4096)
+    assert F.attention_flops(phi3, 4096) == 8 * 4 * 32 * 96 * 2048.5
+    small = dict(phi3, n_layers=4, d_model=256, n_heads=4, n_kv_heads=2,
+                 head_dim=64, d_ff=512, vocab_size=512)
+    mixed = dict(small, window=[8, None, 8, None])
+    assert F.forward_flops_per_token(mixed, 64) == (
+        dense_count_before_the_split(dict(small, window=8), 64)
+        + dense_count_before_the_split(dict(small, window=None), 64)) / 2
+    assert F.layer_windows(mixed) == [8, None, 8, None]
+    assert F.layer_windows(dict(small, window=8)) == [8] * 4
+
+
+def test_flop_counts_from_the_reference_module(monkeypatch):
+    """A configuration's reference module that defines its own counts gives
+    them; ``dense_lm`` defines none, so the dense counts stand."""
+    from types import SimpleNamespace as NS
+    cfg = H.config("phi3-mini-3.8b-8l")
+    assert H.flop_counts(cfg) == (F.forward_flops_per_token,
+                                  F.attention_flops)
+
+    def own_forward(model, seq):
+        return 1e9
+
+    def own_attention(model, seq):
+        return 2e8
+    monkeypatch.setattr(H, "reference_module", lambda c: NS(
+        forward_flops_per_token=own_forward, attention_flops=own_attention))
+    assert H.flop_counts(cfg) == (own_forward, own_attention)
+    assert F.step_flops(cfg["model"], 4096, 10, "fo", own_forward) == 3e10

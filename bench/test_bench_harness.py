@@ -89,6 +89,78 @@ def test_metric_readers_on_a_record():
     assert got["exchange.exposed_s"] is None      # nothing to read: left out
 
 
+def test_span_readers_on_a_record():
+    """The readers of the program's spans: the per-step parts as
+    ``span_reduce`` gives them, and attention's roofline shares from the
+    FLOPs of a step's forwards over its ``attn.flash`` seconds; with no
+    spans, or no ``attn.flash`` time, each finds nothing."""
+    per_step = {"zo_step.forward_device_s": 1.15,
+                "zo_step.direction_device_s": 0.114,
+                "fo_step.backward_device_s": 1.12,
+                "fo_step.update_device_s": 0.144, "trainer.data_s": 0.004}
+    rec = {"attention_flops": 6.6e12, "peak_flops": 197e12, "spans": {
+        "per_step": per_step, "programs": {
+            "jit_zo_step": {"executions": 6, "by_layer": {
+                "model.attn/attn.flash": 6 * 0.216, "model.mlp": 2.8}},
+            "jit_fo_step": {"executions": 2, "by_layer": {
+                "model.attn/attn.flash": 2 * 0.452}}}}}
+    for name, value in per_step.items():
+        assert H.metric_reader(name)(rec) == value
+    assert H.metric_reader("zo_step.attn_flash_roofline")(rec) == \
+        pytest.approx(100 * 2 * 6.6e12 / (0.216 * 197e12))
+    assert H.metric_reader("fo_step.attn_flash_roofline")(rec) == \
+        pytest.approx(100 * 4 * 6.6e12 / (0.452 * 197e12))
+    dense = {**rec, "spans": {"per_step": {}, "programs": {
+        "jit_zo_step": {"executions": 6,
+                        "by_layer": {"model.attn/attn.dense": 3.0}}}}}
+    for name in list(per_step) + ["zo_step.attn_flash_roofline",
+                                  "fo_step.attn_flash_roofline"]:
+        assert H.metric_reader(name)({}) is None
+        assert H.metric_reader(name)(dense) is None
+
+
+def tiny_program(**changes):
+    from types import SimpleNamespace as NS
+    from repro.configs import get_config
+    return NS(cfg=get_config("phi3-mini-3.8b").reduced().with_(**changes))
+
+
+def file_model(tr, **changes):
+    keys = [k for k in H.config("phi3-mini-3.8b-8l")["model"]
+            if k not in ("window", "embed_scale")]
+    return {"name": "tiny", "model": dict(
+        {k: getattr(tr.cfg, k) for k in keys}, embed_scale=True, **changes)}
+
+
+def test_program_config_checked_by_layer_window():
+    """A file's ``window`` is one value for every layer or a list, one a
+    layer, held to the program's ``layer_windows()``."""
+    tr = tiny_program(n_layers=4, window=8, layer_pattern="local_global")
+    assert tr.cfg.layer_windows() == (8, None, 8, None)
+    H.check_program_config(tr, file_model(tr, window=[8, None, 8, None]))
+    for window in (8, None, [None, 8, None, 8], [8, None]):
+        with pytest.raises(SystemExit, match="window"):
+            H.check_program_config(tr, file_model(tr, window=window))
+    full = tiny_program()
+    H.check_program_config(full, file_model(full, window=None))
+    H.check_program_config(full, file_model(full, window=[None] * 2))
+
+
+@pytest.mark.parametrize("key,value", [("n_experts", 8), ("top_k", 6),
+                                       ("d_ff", 1024)])
+def test_program_config_checked_by_field(key, value):
+    """Every key of the file's model that names a field of the program's
+    ``ModelConfig`` is compared; ``embed_scale`` names none."""
+    tr = tiny_program(n_experts=4, top_k=2)
+    ok = file_model(tr, window=None, n_experts=4, top_k=2)
+    H.check_program_config(tr, ok)
+    H.check_program_config(tr, dict(ok, model=dict(ok["model"],
+                                                   embed_scale=False)))
+    with pytest.raises(SystemExit, match=key):
+        H.check_program_config(tr, dict(ok, model=dict(ok["model"],
+                                                       **{key: value})))
+
+
 def test_step_estimates_and_window():
     warm = {"kinds": ["fo", "zo", "zo"], "dts": [6.0, 3.0, 2.3],
             "compile_s": [1.5, 0.7, 0.0]}
@@ -97,6 +169,31 @@ def test_step_estimates_and_window():
     assert H.window_steps(4, 20, est) == 8          # two periods at least
     assert H.window_steps(4, 30, est) == 12
     assert H.window_steps(1, 20, {"fo": 4.5}) == 5
+
+
+def test_window_runs_on_where_the_estimate_overstates_a_period(monkeypatch):
+    """The window is whole periods: where the periods the estimate gave fall
+    short of the seconds, it runs on by as many as its own measured period
+    says reach them."""
+    from types import SimpleNamespace as NS
+    clock = [0.0]
+    monkeypatch.setattr(H, "time", NS(perf_counter=lambda: clock[0]))
+
+    def run(tr, on_step):
+        for t in range(tr.args.steps):
+            kind = "fo" if t % 4 == 0 else "zo"
+            dt = 2.3 if kind == "fo" else 1.3
+            clock[0] += dt
+            on_step(t, kind, 1.0, dt, None, None)
+
+    train, tr = NS(run=run), NS(args=NS(steps=0))
+    est = {"fo": 4.6, "zo": 1.37}                   # a period of 8.71 s
+    win = H.timed_window(train, tr, 4, 20, est)
+    # two estimated periods take 12.4 s: two more reach 20 s
+    assert len(win["dts"]) == 16 and win["wall_s"] == pytest.approx(24.8)
+    assert win["kinds"] == H.step_kinds(4, 4) * 4 and win["compiles"] == 0
+    win = H.timed_window(train, tr, 4, 10, est)
+    assert len(win["dts"]) == 8 and win["wall_s"] == pytest.approx(12.4)
 
 
 def test_seed_program_steps():

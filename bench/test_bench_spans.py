@@ -10,6 +10,7 @@ sys.path.insert(0, BENCH)
 
 import pytest  # noqa: E402
 
+import harness as H  # noqa: E402
 import span_reduce as R  # noqa: E402
 
 TESTDATA = os.path.join(BENCH, "testdata")
@@ -119,3 +120,36 @@ def test_per_step():
         "fo_step.backward_device_s": 2.0, "fo_step.recompute_device_s": 1.0,
         "fo_step.update_device_s": 0.75, "trainer.data_s": 0.01})
     assert R.per_step({}, {}) == {}
+
+
+def test_harness_reads_spans_into_the_record(red, tmp_path):
+    """A traced run's reduction: the recorded trace in a profile directory,
+    read as ``run_cell`` reads it, puts ``span_reduce``'s result under
+    ``rec["spans"]``, and the per-step readers give its numbers; the
+    directory is removed."""
+    import shutil
+    prof = tmp_path / "plugins" / "profile" / "run"
+    prof.mkdir(parents=True)
+    shutil.copy(TRACE, prof / "host.xplane.pb")
+    with open(SCOPES) as f:
+        scopes = json.load(f)
+    logged = []
+    xred, spans = H.read_trace(str(tmp_path), 1, scopes, log=logged.append)
+    assert not tmp_path.exists()
+    assert spans == red
+    assert xred["modules"]["jit_zo_step"] and len(logged) == 2
+    wl = H.workload("phi3-8l.hosgd-t4")
+    cfg, tf = H.config(wl["config"]), H.traffic(wl["traffic"])
+    win = {"wall_s": spans["window_s"], "dts": [0.0075] * 8,
+           "kinds": H.step_kinds(4, 8)}
+    rec = H.trace_record(cfg, tf, 1, win, xred, spans, "TPU v5 lite")
+    assert rec["spans"] is spans and rec["n_steps"] == 8
+    got = H.per_layer_metrics(wl["name"], rec)
+    for name in ("zo_step.forward_device_s", "zo_step.direction_device_s",
+                 "fo_step.backward_device_s", "fo_step.update_device_s",
+                 "trainer.data_s"):
+        assert got[name]["value"] == red["per_step"][name]
+    # recorded before the flash kernels: no attn.flash time, no share
+    assert "zo_step.attn_flash_roofline" not in got
+    assert "fo_step.attn_flash_roofline" not in got
+    assert rec["attention_flops"] == 8 * 4 * 32 * 96 * 2048.5 * 8 * 4096
